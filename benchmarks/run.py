@@ -36,6 +36,9 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     from benchmarks import (
         ablations,
         faults,

@@ -28,7 +28,7 @@ import math
 import random
 from typing import Callable
 
-from repro.data.pipeline import PipelinePolicy, RawRecord, realize_lengths
+from repro.data.pipeline import PipelinePolicy, RawRecord, _unit_hash, realize_lengths
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,6 +47,12 @@ class DatasetSpec:
         return realize_lengths(self.records(seed), self.policy, epoch)
 
 
+def _record_rng(name: str, seed: int) -> random.Random:
+    """Per-(dataset, seed) generator, identical in every process (no
+    dependence on ``PYTHONHASHSEED``)."""
+    return random.Random(int(_unit_hash("records", name, seed) * (1 << 63)))
+
+
 # ---------------------------------------------------------------------------
 # Six synthetic audit distributions (App. I).
 # ---------------------------------------------------------------------------
@@ -58,8 +64,6 @@ def _records_from_lengths(lengths: list[int]) -> list[RawRecord]:
     With strength=0 the pipeline maps chars -> tokens deterministically per
     identity; we solve chars for the desired token count.
     """
-    from repro.data.pipeline import _unit_hash
-
     records = []
     policy = PipelinePolicy()
     for i, target in enumerate(lengths):
@@ -72,7 +76,7 @@ def _records_from_lengths(lengths: list[int]) -> list[RawRecord]:
 
 def _synthetic(name: str, gen: Callable[[random.Random], int], size: int = 1000):
     def make(size_: int, seed: int) -> list[RawRecord]:
-        rng = random.Random((name, seed).__hash__() & 0x7FFFFFFF)
+        rng = _record_rng(name, seed)
         return _records_from_lengths([gen(rng) for _ in range(size_)])
 
     return DatasetSpec(
@@ -116,7 +120,7 @@ def _lognormal_lengths(
 
 def _clone(name, size, mean, cv, lo, hi, cutoff, multimodal=False):
     def make(size_: int, seed: int) -> list[RawRecord]:
-        rng = random.Random((name, seed).__hash__() & 0x7FFFFFFF)
+        rng = _record_rng(name, seed)
         lengths = _lognormal_lengths(rng, size_, mean, cv, lo, hi)
         records = _records_from_lengths(lengths)
         if multimodal:
@@ -165,7 +169,7 @@ DATASET_CLONES = {
 def _make_mmmix(size_: int, seed: int) -> list[RawRecord]:
     # Bimodal production mix (App. I): 45% short OCR/VQA labels, 30% mid
     # VQA/caption, 25% long-form captioning; calibrated to CV≈0.85.
-    rng = random.Random(("mmmix", seed).__hash__() & 0x7FFFFFFF)
+    rng = _record_rng("mmmix", seed)
     lengths = []
     for _ in range(size_):
         u = rng.random()

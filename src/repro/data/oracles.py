@@ -185,7 +185,7 @@ def hfg_schedule(
 ) -> list[list[Group | None]]:
     """HF group_by_length-style randomized fixed-batch oracle (App. J)."""
     lengths = cache.lengths
-    rng = random.Random((seed, epoch, "hfg").__hash__() & 0x7FFFFFFF)
+    rng = random.Random(f"hfg|{seed}|{epoch}")  # str seeds hash stably
     order = list(range(len(lengths)))
     rng.shuffle(order)
     mega = batch_size * megabatch_factor

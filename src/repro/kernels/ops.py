@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute under ``interpret=True``; on TPU
-they compile through Mosaic.  ``flash_attention`` carries a ``custom_vjp``
-whose backward runs the dedicated Pallas dq/dkv kernels
+On the CPU backend (tests) the kernels execute under ``interpret=True``; on
+TPU they always compile through Mosaic — nothing on this route falls back to
+interpret mode when a chip is present.  ``flash_attention`` carries a
+``custom_vjp`` whose backward runs the dedicated Pallas dq/dkv kernels
 (:func:`~repro.kernels.flash_attention.segment_flash_attention_bwd`) from the
 saved ``(q, k, v, out, lse)`` residuals — the recompute-free two-pass
 formulation, so the training backward never round-trips through the O(S²)
@@ -43,7 +44,8 @@ from repro.kernels.ssd_scan import ssd_scan
 GRID_MODES = ("dense", "pruned", "auto")
 
 
-def _on_cpu() -> bool:
+def _interpret() -> bool:
+    """Pallas interpret mode exactly on the CPU backend."""
     return jax.default_backend() == "cpu"
 
 
@@ -66,12 +68,12 @@ def _flash(q, k, v, segment_ids, causal, block_q, block_kv, grid):
         return segment_flash_attention_pruned(
             q, k, v, segment_ids,
             causal=causal, block_q=block_q, block_kv=block_kv,
-            interpret=_on_cpu(), expect_resolved=True,
+            interpret=_interpret(), expect_resolved=True,
         )
     return segment_flash_attention(
         q, k, v, segment_ids,
         causal=causal, block_q=block_q, block_kv=block_kv,
-        interpret=_on_cpu(), expect_resolved=True,
+        interpret=_interpret(), expect_resolved=True,
     )
 
 
@@ -84,7 +86,7 @@ def _flash_fwd(q, k, v, segment_ids, causal, block_q, block_kv, grid):
     out, lse = fwd(
         q, k, v, segment_ids,
         causal=causal, block_q=block_q, block_kv=block_kv,
-        interpret=_on_cpu(), return_residuals=True, expect_resolved=True,
+        interpret=_interpret(), return_residuals=True, expect_resolved=True,
     )
     return out, (q, k, v, segment_ids, out, lse)
 
@@ -99,7 +101,7 @@ def _flash_bwd(causal, block_q, block_kv, grid, res, g):
     dq, dk, dv = bwd(
         q, k, v, segment_ids, out, lse, g,
         causal=causal, block_q=block_q, block_kv=block_kv,
-        interpret=_on_cpu(), expect_resolved=True,
+        interpret=_interpret(), expect_resolved=True,
     )
     return dq, dk, dv, None
 
@@ -125,5 +127,5 @@ def ssd_chunked_scan(x, dt, a, b_proj, c_proj, *, chunk: int = 256):
     adt = a[None, None, :] * dt
     return ssd_scan(
         x, adt.astype(jnp.float32), dt.astype(jnp.float32), b_proj, c_proj,
-        chunk=chunk, interpret=_on_cpu(),
+        chunk=chunk, interpret=_interpret(),
     )

@@ -22,13 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(
@@ -111,6 +105,6 @@ def ssd_scan(
         in_specs=[x_spec, sc_spec, sc_spec, bn_spec, bn_spec],
         out_specs=x_spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        scratch_shapes=[_VMEM((p, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
     )(x, adt[..., None], dt[..., None], b_p, c_p)

@@ -38,7 +38,6 @@ def _sharded_flash_fn(mesh, grid: str, *, causal=True, block_q=128, block_kv=128
     """shard_map'd loss+grads over the flash route, batch sharded on DP."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.ops import flash_attention
@@ -61,12 +60,12 @@ def _sharded_flash_fn(mesh, grid: str, *, causal=True, block_q=128, block_kv=128
         return loss[None], grads  # rank-1 per-shard loss, concat over DP
 
     def sharded(q, k, v, seg):
-        loss, grads = shard_map(
+        loss, grads = jax.shard_map(
             local_body,
             mesh=mesh,
             in_specs=(batch_spec, batch_spec, batch_spec, batch_spec),
             out_specs=(batch_spec, (batch_spec, batch_spec, batch_spec)),
-            check_rep=False,
+            check_vma=False,
         )(q, k, v, seg)
         # Per-shard partial losses; summing them is the global objective.
         return jnp.sum(loss), grads

@@ -9,17 +9,7 @@ import and then calls these.
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed after jax 0.4.x; older CPU containers lack it
-    from jax.sharding import AxisType
-
-    def _axis_kwargs(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-
-except ImportError:  # pragma: no cover - depends on installed jax
-
-    def _axis_kwargs(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,7 +22,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -42,7 +32,7 @@ def make_host_mesh(model_parallel: int = 1):
     return jax.make_mesh(
         (n // model_parallel, model_parallel),
         ("data", "model"),
-        **_axis_kwargs(2),
+        axis_types=(AxisType.Auto,) * 2,
     )
 
 
@@ -64,7 +54,7 @@ def make_sim_multihost_mesh(num_hosts: int, model_parallel: int = 1):
     return jax.make_mesh(
         (num_hosts, n // (num_hosts * model_parallel), model_parallel),
         ("host", "data", "model"),
-        **_axis_kwargs(3),
+        axis_types=(AxisType.Auto,) * 3,
     )
 
 

@@ -20,11 +20,13 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import LM
 from repro.serve import ContinuousBatchingEngine, ServeConfig, synth_request_trace
 
 
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_0_6b")
     ap.add_argument("--smoke", action="store_true")

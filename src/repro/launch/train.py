@@ -22,8 +22,9 @@ import jax
 from repro.configs import get_config, get_smoke_config
 from repro.core import BucketSpec, OdbConfig
 from repro.data import OnlineDynamicLoader, get_dataset
-from repro.stream import EpochAborted
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import LM
+from repro.stream import EpochAborted
 from repro.train.optimizer import OptimizerConfig
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -54,14 +55,22 @@ def _calibrate_layout(
     return cal["layout"]
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_0_6b")
-    ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
+    ap.add_argument(
+        "--smoke", action="store_true",
+        help="the architecture's reduced-width smoke config (quick runs and "
+             "CPU tests); without it the published full-width config",
+    )
     ap.add_argument("--dataset", default="ultrachat")
     ap.add_argument("--data-scale", type=float, default=0.002)
     ap.add_argument("--world", type=int, default=4)
     ap.add_argument("--steps", type=int, default=50)
+    ap.add_argument(
+        "--log-every", type=int, default=5,
+        help="record (and print at exit) the loss line every N steps",
+    )
     ap.add_argument("--l-max", type=int, default=4096)
     ap.add_argument("--buffer", type=int, default=256)
     ap.add_argument("--prefetch", type=int, default=64)
@@ -153,7 +162,43 @@ def main() -> None:
              "XLA_FLAGS=--xla_force_host_platform_device_count=N to give "
              "each simulated host its own device block; must divide --world",
     )
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def build_loader(args: argparse.Namespace, vocab_size: int) -> OnlineDynamicLoader:
+    """The ODB loader these arguments describe (``--layout auto`` runs the
+    calibration probe first)."""
+    dataset = get_dataset(args.dataset, scale=args.data_scale)
+    odb_cfg = OdbConfig(
+        l_max=args.l_max, buffer_size=args.buffer,
+        prefetch_factor=args.prefetch, num_workers=4,
+        join_mode=not args.non_join,
+        round_deadline_s=args.round_deadline,
+        round_retries=args.round_retries,
+        max_quarantine=args.max_quarantine,
+    )
+    bucket_spec = BucketSpec(min_len=128, max_len=16384, max_count=1024)
+    layout = args.layout
+    if layout == "auto":
+        layout = _calibrate_layout(
+            dataset, args.world, odb_cfg, args.calibration_steps, bucket_spec
+        )
+    return OnlineDynamicLoader(
+        dataset,
+        world_size=args.world,
+        config=odb_cfg,
+        bucket_spec=bucket_spec,
+        layout=layout,
+        vocab_size=vocab_size,
+        num_hosts=args.hosts,
+    )
+
+
+def main(argv: list[str] | None = None) -> Trainer:
+    """Parse ``argv`` (default: the command line), train, and return the
+    finished :class:`Trainer` (its ``history``, routes and ``loader``)."""
+    configure_compile_cache()
+    args = parse_args(argv)
 
     reporter = None
     if args.telemetry:
@@ -175,36 +220,14 @@ def main() -> None:
         attn_autotune=args.attn_autotune,
     )
     model = LM(cfg)
-    dataset = get_dataset(args.dataset, scale=args.data_scale)
-    odb_cfg = OdbConfig(
-        l_max=args.l_max, buffer_size=args.buffer,
-        prefetch_factor=args.prefetch, num_workers=4,
-        join_mode=not args.non_join,
-        round_deadline_s=args.round_deadline,
-        round_retries=args.round_retries,
-        max_quarantine=args.max_quarantine,
-    )
-    bucket_spec = BucketSpec(min_len=128, max_len=16384, max_count=1024)
-    layout = args.layout
-    if layout == "auto":
-        layout = _calibrate_layout(
-            dataset, args.world, odb_cfg, args.calibration_steps, bucket_spec
-        )
-    loader = OnlineDynamicLoader(
-        dataset,
-        world_size=args.world,
-        config=odb_cfg,
-        bucket_spec=bucket_spec,
-        layout=layout,
-        vocab_size=cfg.vocab_size,
-        num_hosts=args.hosts,
-    )
+    loader = build_loader(args, cfg.vocab_size)
+    layout = loader.layout.name
     trainer = Trainer(
         model, loader,
         OptimizerConfig(total_steps=max(args.steps, 100)),
         TrainerConfig(
             checkpoint_dir=args.checkpoint_dir, checkpoint_every=20,
-            log_every=5, max_steps=args.steps,
+            log_every=args.log_every, max_steps=args.steps,
             streaming=not args.eager, prefetch=not args.no_prefetch,
             prefetch_depth=args.prefetch_depth, lookahead=args.lookahead,
             device_put=args.device_put, num_workers=args.num_workers,
@@ -282,6 +305,7 @@ def main() -> None:
             print(f"[train] telemetry {kind}: {path}")
     if scrape is not None:
         scrape.stop()
+    return trainer
 
 
 if __name__ == "__main__":
