@@ -402,6 +402,25 @@ class OdbProtocolEngine:
 
     # -- one outer round -----------------------------------------------------------
     def run_round(self) -> RoundRecord:
+        """One outer round; under the ``dgap/round`` span unless this is an
+        audit-only replay."""
+        if not self.record_telemetry:
+            return self._round()
+        with obs.span("dgap/round", cat="protocol") as span:
+            record = self._round()
+            span.note(
+                round=record.round_index,
+                target=record.target,
+                emitted_views=record.emitted_views,
+            )
+        self._m_rounds.inc()
+        self._m_emitted.inc(record.emitted_views)
+        self._m_round_dur.observe(record.duration_s)
+        if self.on_round is not None:
+            self.on_round(record)
+        return record
+
+    def _round(self) -> RoundRecord:
         round_t0 = time.perf_counter()
         cfg = self.config
         # Phase 1: fetch/drain on every unfinished rank.
@@ -513,21 +532,6 @@ class OdbProtocolEngine:
         )
         self.records.append(record)
         self._round_index += 1
-        if self.record_telemetry:
-            self._m_rounds.inc()
-            self._m_emitted.inc(emitted_views)
-            self._m_round_dur.observe(duration_s)
-            obs.default_tracer().complete(
-                "dgap/round",
-                round_t0,
-                duration_s,
-                cat="protocol",
-                round=record.round_index,
-                target=target,
-                emitted_views=emitted_views,
-            )
-            if self.on_round is not None:
-                self.on_round(record)
         return record
 
     # -- full logical iteration ---------------------------------------------------

@@ -269,10 +269,12 @@ def _row_groups(b: int, walk: _Walk) -> list[tuple[int, int]]:
     return list(zip(edges[:-1], edges[1:]))
 
 
-def _launch(kernel, walk, *, h, d, in_kinds, args, out_kinds, out_shapes,
+def _launch(kernel, walk, *, name, h, d, in_kinds, args, out_kinds, out_shapes,
             scratch, tables, interpret):
-    """Run one pallas_call over ``walk``; the pruned walk prefetches its
-    tables and splits the batch into SMEM-bounded row groups."""
+    """Run one pallas_call named ``name`` over ``walk``; the pruned walk
+    prefetches its tables and splits the batch into SMEM-bounded row groups.
+    Every grid and row group of one pass carries the same name, which the
+    profiler trace shows on the kernel's device events."""
     in_specs = [_spec(k, walk, d) for k in in_kinds]
     out_specs = [_spec(k, walk, d) for k in out_kinds]
     params = pltpu.CompilerParams(
@@ -295,13 +297,14 @@ def _launch(kernel, walk, *, h, d, in_kinds, args, out_kinds, out_shapes,
                     out_specs=out_specs, scratch_shapes=scratch,
                 ),
                 out_shape=shapes, compiler_params=params, interpret=interpret,
+                name=name,
             )
             results.append(call(*(t[r0:r1] for t in tables), *rows))
         else:
             call = pl.pallas_call(
                 kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
                 out_shape=shapes, scratch_shapes=scratch,
-                compiler_params=params, interpret=interpret,
+                compiler_params=params, interpret=interpret, name=name,
             )
             results.append(call(*rows))
     if len(results) == 1:
@@ -420,7 +423,7 @@ def _forward(q, k, v, segment_ids, *, pruned, causal, scale, block_q,
         scale=scale,
     )
     outs = _launch(
-        kernel, walk, h=h, d=d,
+        kernel, walk, name="flash_fwd", h=h, d=d,
         in_kinds=["q", "kv", "kv"] + (["qseg", "kseg"] if has_seg else []),
         args=[qh, _heads_major(k), _heads_major(v)] + seg_args,
         out_kinds=out_kinds, out_shapes=out_shapes,
@@ -593,7 +596,7 @@ def _backward(q, k, v, segment_ids, out, lse, do, *, pruned, causal, scale,
     walk = _Walk(q_stationary=True, **common)
     (dq,) = _launch(
         functools.partial(_bwd_dq_kernel, walk=walk, has_seg=has_seg, scale=scale),
-        walk, h=h, d=d, in_kinds=in_kinds, args=args,
+        walk, name="flash_dq", h=h, d=d, in_kinds=in_kinds, args=args,
         out_kinds=["q"], out_shapes=[jax.ShapeDtypeStruct(qh.shape, q.dtype)],
         scratch=[pltpu.VMEM((block_q, d), jnp.float32)],
         tables=(tables.kv_idx, tables.kv_count) if pruned else None,
@@ -607,7 +610,7 @@ def _backward(q, k, v, segment_ids, out, lse, do, *, pruned, causal, scale,
     walk = _Walk(q_stationary=False, **common)
     dk, dv = _launch(
         functools.partial(_bwd_dkv_kernel, walk=walk, has_seg=has_seg, scale=scale),
-        walk, h=h, d=d, in_kinds=in_kinds, args=args,
+        walk, name="flash_dkv", h=h, d=d, in_kinds=in_kinds, args=args,
         out_kinds=["kv", "kv"],
         out_shapes=[
             jax.ShapeDtypeStruct(kh.shape, k.dtype),
@@ -762,7 +765,7 @@ def live_tile_counts(
                     and k_pos.max() >= q_pos.min()
                 ):
                     seg_live += 1
-    out = {
+    return {
         "tiles": total,
         "block_q": block_q,
         "block_kv": block_kv,
@@ -771,14 +774,3 @@ def live_tile_counts(
         "causal_live_fraction": causal_live / total if total else 0.0,
         "segment_live_fraction": seg_live / total if total else 0.0,
     }
-    from repro import obs  # deferred: keep kernel import time lean
-
-    obs.gauge(
-        "kernel_live_tile_fraction",
-        help="fraction of attention tiles surviving the block-skip rule",
-        mode="causal",
-    ).set(out["causal_live_fraction"])
-    obs.gauge(
-        "kernel_live_tile_fraction", mode="segment"
-    ).set(out["segment_live_fraction"])
-    return out

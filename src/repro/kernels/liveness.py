@@ -188,7 +188,7 @@ def fetched_tile_counts(
 
     steps = bsz * heads * nq * nk
     tile_bytes = 2 * block_kv * head_dim * itemsize  # k + v
-    out = {
+    return {
         "grid": [bsz, heads, nq, nk],
         "block_q": block_q,
         "block_kv": block_kv,
@@ -202,22 +202,3 @@ def fetched_tile_counts(
         "dense_fetched_bytes": dense_fetches * tile_bytes,
         "pruned_fetched_bytes": pruned_fetches * tile_bytes,
     }
-    from repro import obs  # deferred: keep kernel import time lean
-
-    obs.gauge(
-        "kernel_fetched_tile_fraction",
-        help="fraction of forward-grid steps that DMA a fresh kv tile",
-        grid="dense",
-    ).set(out["dense_fetched_fraction"])
-    obs.gauge("kernel_fetched_tile_fraction", grid="pruned").set(
-        out["pruned_fetched_fraction"]
-    )
-    obs.gauge(
-        "kernel_fetched_kv_bytes",
-        help="kv bytes DMA'd by the forward grid per batch",
-        grid="dense",
-    ).set(float(out["dense_fetched_bytes"]))
-    obs.gauge("kernel_fetched_kv_bytes", grid="pruned").set(
-        float(out["pruned_fetched_bytes"])
-    )
-    return out

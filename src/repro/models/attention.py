@@ -491,17 +491,19 @@ def mla_attention(
 
 
 def apply_attention(params, x, cfg, positions, segments=None, cache=None, cache_index=None, mesh=None, dest_slot=None):
-    if cfg.attn_kind == "mla":
-        if dest_slot is not None:
-            raise NotImplementedError(
-                "slot-scatter prefill needs the GQA cache layout; MLA serving "
-                "stays on the per-request prefill path (DESIGN.md §12)"
-            )
-        return mla_attention(params, x, cfg, positions, segments, cache, cache_index)
-    return gqa_attention(
-        params, x, cfg, positions, segments, cache, cache_index,
-        mesh=mesh, dest_slot=dest_slot,
-    )
+    """The attention mixer; its operations carry the ``attention`` scope."""
+    if cfg.attn_kind == "mla" and dest_slot is not None:
+        raise NotImplementedError(
+            "slot-scatter prefill needs the GQA cache layout; MLA serving "
+            "stays on the per-request prefill path (DESIGN.md §12)"
+        )
+    with jax.named_scope("attention"):
+        if cfg.attn_kind == "mla":
+            return mla_attention(params, x, cfg, positions, segments, cache, cache_index)
+        return gqa_attention(
+            params, x, cfg, positions, segments, cache, cache_index,
+            mesh=mesh, dest_slot=dest_slot,
+        )
 
 
 def init_kv_cache(cfg, batch: int, max_len: int, dtype) -> KVCache | MLACache:

@@ -105,12 +105,14 @@ def make_mlp_params(key, d_model: int, d_ff: int, gated: bool, dtype) -> Params:
 
 
 def apply_mlp(params: Params, x: jax.Array, act: str, gated: bool) -> jax.Array:
-    h = x @ params["w_in"]
-    if gated:
-        h = act_fn(act)(x @ params["w_gate"]) * h
-    else:
-        h = act_fn(act)(h)
-    return h @ params["w_out"]
+    """The feed-forward block; its operations carry the ``mlp`` scope."""
+    with jax.named_scope("mlp"):
+        h = x @ params["w_in"]
+        if gated:
+            h = act_fn(act)(x @ params["w_gate"]) * h
+        else:
+            h = act_fn(act)(h)
+        return h @ params["w_out"]
 
 
 # -- losses --------------------------------------------------------------------

@@ -179,9 +179,15 @@ class LM:
 
     # -- public entry points ---------------------------------------------------------
     def forward(self, params: Params, batch: dict) -> jax.Array:
+        return self._logits(params, self._hidden(params, batch))
+
+    def _hidden(self, params: Params, batch: dict) -> jax.Array:
         x = self._embed(params, batch)
         positions, segments = self._positions_segments(batch, x.shape[1])
         x, _ = self._run_stack(params, x, positions, segments)
+        return x
+
+    def _logits(self, params: Params, x: jax.Array) -> jax.Array:
         x = apply_norm(params["final_norm"], x, self.cfg)
         logits = x @ params["unembed"]
         if self.cfg.logits_fp32:
@@ -195,11 +201,16 @@ class LM:
         return logits
 
     def loss_sums(self, params: Params, batch: dict):
-        """(loss_sum, token_count) over valid targets — Eq. 2 primitives."""
-        logits = self.forward(params, batch)
-        return masked_cross_entropy(
-            logits, batch["labels"], batch["loss_mask"], fp32=self.cfg.logits_fp32
-        )
+        """(loss_sum, token_count) over valid targets — Eq. 2 primitives.
+
+        The final norm, the unembedding and the cross-entropy carry the
+        ``lm_loss`` scope."""
+        x = self._hidden(params, batch)
+        with jax.named_scope("lm_loss"):
+            return masked_cross_entropy(
+                self._logits(params, x), batch["labels"], batch["loss_mask"],
+                fp32=self.cfg.logits_fp32,
+            )
 
     # -- serving ----------------------------------------------------------------------
     def init_caches(self, batch: int, max_len: int) -> Params:

@@ -42,7 +42,7 @@ from repro.obs.report import (
     enable_telemetry,
 )
 from repro.obs.scrape import ScrapeServer, start_scrape_server
-from repro.obs.trace import NULL_SPAN, Span, SpanTracer, default_tracer
+from repro.obs.trace import NULL_SPAN, Span, SpanTracer, default_tracer, name_os_thread
 
 __all__ = [
     "DEFAULT_BUCKETS",
@@ -69,6 +69,7 @@ __all__ = [
     "gauge",
     "histogram",
     "instant",
+    "name_os_thread",
     "span",
     "start_scrape_server",
 ]

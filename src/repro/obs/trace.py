@@ -5,12 +5,22 @@ and *instant* events into a thread-safe bounded ring buffer, exported as the
 ``chrome://tracing`` / Perfetto trace-event JSON format — so one telemetry-
 enabled epoch renders as a timeline: protocol rounds inside stream steps,
 prefetch producer staging against consumer waits, serve admit/prefill/decode
-inside engine ticks, realize/pad/device_put/compute inside train steps.
+inside engine ticks, realize/assemble/dispatch/log inside train steps.
+
+Every span has a second sink: where JAX can be imported, it also opens a
+``jax.profiler.TraceAnnotation`` of the same name, whether or not the ring
+is enabled.  A profiler trace (``jax.profiler.start_trace``) then holds the
+program's spans on the device trace's clock, so an idle gap on the device
+can be named by what the host was doing; with no profiler running an
+annotation records nothing.  JAX is imported on the first span, never at
+import of this module, which stays stdlib-only.
 
 Properties the instrumented hot paths rely on:
 
-  * **disabled is free** — ``span()`` on a disabled tracer returns the one
-    shared :data:`NULL_SPAN` context manager (no allocation, no clock read);
+  * **disabled is cheap** — ``span()`` on a disabled tracer opens only the
+    profiler annotation (a few microseconds), and without JAX returns the
+    one shared :data:`NULL_SPAN` context manager (no allocation, no clock
+    read);
   * **bounded memory** — the ring holds ``capacity`` events; overflow drops
     the *oldest* (the tail of a long run is what post-mortems need) and is
     accounted in :attr:`dropped`, never silent;
@@ -26,17 +36,47 @@ exactly what lexically nested ``with tracer.span(...)`` blocks produce.
 from __future__ import annotations
 
 import collections
+import ctypes
+import functools
 import json
 import os
 import pathlib
 import threading
 import time
 
-__all__ = ["NULL_SPAN", "Span", "SpanTracer", "default_tracer"]
+__all__ = ["NULL_SPAN", "Span", "SpanTracer", "default_tracer", "name_os_thread"]
+
+_PR_SET_NAME = 15  # prctl option: name the calling thread
+
+
+@functools.cache
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where JAX cannot be imported."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+def name_os_thread(name: str) -> None:
+    """Give the calling thread an OS-level name (Linux, at most 15 bytes).
+
+    The profiler names a host thread's line in its trace after the OS
+    thread, which Python before 3.14 leaves named after the process; so
+    every Python thread would read ``python``, and a reader that keys lines
+    by name would merge or drop them.  Call it before the thread's first
+    span.  Elsewhere than Linux it does nothing.
+    """
+    try:
+        prctl = ctypes.CDLL(None).prctl
+    except (OSError, AttributeError):
+        return
+    prctl(_PR_SET_NAME, name.encode()[:15], 0, 0, 0)
 
 
 class _NullSpan:
-    """Shared no-op context manager (disabled-tracer fast path)."""
+    """Shared no-op context manager (no ring, no profiler)."""
 
     __slots__ = ()
 
@@ -46,22 +86,35 @@ class _NullSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def note(self, **args) -> None:
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
 
 class Span:
-    """One live ``with``-scope; emits a single X event at exit."""
+    """One live ``with``-scope: a profiler annotation while it is open, and
+    one X event at exit when the tracer's ring is enabled."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._annotation = None
+
+    def note(self, **args) -> None:
+        """Attach args known only at the end of the scope."""
+        self.args.update(args)
 
     def __enter__(self) -> "Span":
+        annotation = _profiler_annotation()
+        if annotation is not None:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
         self._t0 = self._tracer.clock()
         return self
 
@@ -70,6 +123,9 @@ class Span:
         self._tracer.complete(
             self.name, self._t0, t1 - self._t0, cat=self.cat, **self.args
         )
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         return False
 
 
@@ -117,8 +173,9 @@ class SpanTracer:
             self._emitted += 1
 
     def span(self, name: str, cat: str = "", **args):
-        """Context manager recording one complete (``X``) event on exit."""
-        if not self.enabled:
+        """Context manager opening a profiler annotation and, when the ring
+        is enabled, recording one complete (``X``) event on exit."""
+        if not self.enabled and _profiler_annotation() is None:
             return NULL_SPAN
         return Span(self, name, cat, args)
 

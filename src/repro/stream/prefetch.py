@@ -163,22 +163,21 @@ class PrefetchIterator(Generic[T]):
 
     # -- producer side ---------------------------------------------------------
     def _produce(self, it: Iterator[T]) -> None:
+        # A line of its own in a profiler trace, apart from the consumer's.
+        obs.name_os_thread("odb-prefetch")
         try:
-            tracer = obs.default_tracer()
             while not self._stop.is_set():
-                t0 = time.perf_counter()
-                try:
-                    item = next(it)
-                except StopIteration:
-                    break
-                if self._stage is not None:
-                    item = self._stage(item)
-                dt = time.perf_counter() - t0
-                self.stats.produce_s += dt
-                tracer.complete(
-                    "prefetch/produce", t0, dt, cat="prefetch",
-                    item=self.stats.produced,
-                )
+                with obs.span(
+                    "prefetch/produce", cat="prefetch", item=self.stats.produced
+                ):
+                    t0 = time.perf_counter()
+                    try:
+                        item = next(it)
+                    except StopIteration:
+                        break
+                    if self._stage is not None:
+                        item = self._stage(item)
+                    self.stats.produce_s += time.perf_counter() - t0
                 # Blocks on a full queue; a close() wakes it immediately
                 # (Event-signaled, not put-polled) and returns False.
                 if not self._queue.put(item):
@@ -203,28 +202,12 @@ class PrefetchIterator(Generic[T]):
             hit = True
         except queue.Empty:
             hit = False
-            t0 = time.perf_counter()
-            while True:
-                try:
-                    item = self._queue.get(timeout=0.1)
-                    break
-                except queue.Empty:
-                    # Producer dead with nothing queued (e.g. close() drained
-                    # the sentinel): the stream is over, don't block forever —
-                    # but never swallow a captured producer error into a bare
-                    # StopIteration (the pre-fix masking bug).
-                    if self._finished or not self._thread.is_alive():
-                        self._finished = True
-                        if self._error is not None:
-                            error, self._error = self._error, None
-                            raise error
-                        raise StopIteration from None
-            waited = time.perf_counter() - t0
+            with obs.span("prefetch/wait", cat="prefetch"):
+                t0 = time.perf_counter()
+                item = self._wait()
+                waited = time.perf_counter() - t0
             self.stats.wait_s += waited
             self._m_wait.inc(waited)
-            obs.default_tracer().complete(
-                "prefetch/wait", t0, waited, cat="prefetch"
-            )
         if item is _END:
             # The terminal sentinel is not a data request; don't score it.
             self._finished = True
@@ -241,6 +224,23 @@ class PrefetchIterator(Generic[T]):
         self.stats.consumed += 1
         self._m_depth.set(self._queue.qsize())
         return item
+
+    def _wait(self):
+        """Block until the producer stages an item."""
+        while True:
+            try:
+                return self._queue.get(timeout=0.1)
+            except queue.Empty:
+                # Producer dead with nothing queued (e.g. close() drained
+                # the sentinel): the stream is over, don't block forever —
+                # but never swallow a captured producer error into a bare
+                # StopIteration (the pre-fix masking bug).
+                if self._finished or not self._thread.is_alive():
+                    self._finished = True
+                    if self._error is not None:
+                        error, self._error = self._error, None
+                        raise error
+                    raise StopIteration from None
 
     def close(self, timeout: float | None = None) -> None:
         """Stop the producer and discard staged items (consumer gave up).

@@ -62,35 +62,37 @@ def clip_by_global_norm(grads, max_norm: float):
 
 
 def adamw_update(params: Params, grads: Params, opt_state: dict, cfg: OptimizerConfig):
-    """One AdamW step; returns (new_params, new_opt_state, metrics)."""
-    grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
-    step = opt_state["step"] + 1
-    lr = cosine_lr(step.astype(jnp.float32), cfg)
-    b1, b2 = cfg.betas
-    bc1 = 1.0 - b1 ** step.astype(jnp.float32)
-    bc2 = 1.0 - b2 ** step.astype(jnp.float32)
-    mdt = jnp.dtype(cfg.moment_dtype)
+    """One AdamW step; returns (new_params, new_opt_state, metrics).  The
+    clip and the update carry the ``adamw`` scope."""
+    with jax.named_scope("adamw"):
+        grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
+        step = opt_state["step"] + 1
+        lr = cosine_lr(step.astype(jnp.float32), cfg)
+        b1, b2 = cfg.betas
+        bc1 = 1.0 - b1 ** step.astype(jnp.float32)
+        bc2 = 1.0 - b2 ** step.astype(jnp.float32)
+        mdt = jnp.dtype(cfg.moment_dtype)
 
-    def upd(p, g, m, v):
-        g32 = g.astype(jnp.float32)
-        m32 = b1 * m.astype(jnp.float32) + (1 - b1) * g32
-        v32 = b2 * v.astype(jnp.float32) + (1 - b2) * jnp.square(g32)
-        mh = m32 / bc1
-        vh = v32 / bc2
-        delta = mh / (jnp.sqrt(vh) + cfg.eps) + cfg.weight_decay * p.astype(jnp.float32)
-        newp = p.astype(jnp.float32) - lr * delta
-        return newp.astype(p.dtype), m32.astype(mdt), v32.astype(mdt)
+        def upd(p, g, m, v):
+            g32 = g.astype(jnp.float32)
+            m32 = b1 * m.astype(jnp.float32) + (1 - b1) * g32
+            v32 = b2 * v.astype(jnp.float32) + (1 - b2) * jnp.square(g32)
+            mh = m32 / bc1
+            vh = v32 / bc2
+            delta = mh / (jnp.sqrt(vh) + cfg.eps) + cfg.weight_decay * p.astype(jnp.float32)
+            newp = p.astype(jnp.float32) - lr * delta
+            return newp.astype(p.dtype), m32.astype(mdt), v32.astype(mdt)
 
-    flat_p, tdef = jax.tree_util.tree_flatten(params)
-    flat_g = jax.tree_util.tree_leaves(grads)
-    flat_m = jax.tree_util.tree_leaves(opt_state["m"])
-    flat_v = jax.tree_util.tree_leaves(opt_state["v"])
-    out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
-    new_p = tdef.unflatten([o[0] for o in out])
-    new_m = tdef.unflatten([o[1] for o in out])
-    new_v = tdef.unflatten([o[2] for o in out])
-    return (
-        new_p,
-        {"m": new_m, "v": new_v, "step": step},
-        {"lr": lr, "grad_norm": gnorm},
-    )
+        flat_p, tdef = jax.tree_util.tree_flatten(params)
+        flat_g = jax.tree_util.tree_leaves(grads)
+        flat_m = jax.tree_util.tree_leaves(opt_state["m"])
+        flat_v = jax.tree_util.tree_leaves(opt_state["v"])
+        out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
+        new_p = tdef.unflatten([o[0] for o in out])
+        new_m = tdef.unflatten([o[1] for o in out])
+        new_v = tdef.unflatten([o[2] for o in out])
+        return (
+            new_p,
+            {"m": new_m, "v": new_v, "step": step},
+            {"lr": lr, "grad_norm": gnorm},
+        )

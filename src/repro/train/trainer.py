@@ -21,9 +21,10 @@ Two execution paths:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import math
 import time
-from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -130,14 +131,14 @@ def make_train_step(model: LM, opt_cfg: OptimizerConfig):
     return train_step
 
 
-def _timed_phase(span_name: str, metric: str, help: str, fn: Callable):
-    """Run one step phase under a trace span + cumulative seconds counter."""
-    t0 = time.perf_counter()
-    out = fn()
-    dt = time.perf_counter() - t0
-    obs.counter(metric, help=help, unit="seconds").inc(dt)
-    obs.default_tracer().complete(span_name, t0, dt, cat="train")
-    return out
+@contextlib.contextmanager
+def _timed_phase(span_name: str, metric: str, help: str):
+    """Run one step phase under a span (ring and profiler) and add its host
+    time to a cumulative seconds counter."""
+    with obs.span(span_name, cat="train"):
+        t0 = time.perf_counter()
+        yield
+        obs.counter(metric, help=help, unit="seconds").inc(time.perf_counter() - t0)
 
 
 def assemble_model_batch(loader_step: LoaderStep, layout: BatchLayout) -> dict:
@@ -152,16 +153,16 @@ def assemble_model_batch(loader_step: LoaderStep, layout: BatchLayout) -> dict:
     """
     arrays = loader_step.device
     if arrays is None:
-        host = _timed_phase(
+        with _timed_phase(
             "train/pad", "train_pad_seconds_total",
             "host-side batch padding/assembly time",
-            lambda: global_batch_arrays(loader_step.batches, layout),
-        )
-        arrays = _timed_phase(
+        ):
+            host = global_batch_arrays(loader_step.batches, layout)
+        with _timed_phase(
             "train/device_put", "train_device_put_seconds_total",
             "host-to-device transfer dispatch time",
-            lambda: {k: jnp.asarray(v) for k, v in host.items()},
-        )
+        ):
+            arrays = {k: jnp.asarray(v) for k, v in host.items()}
     tokens = arrays["tokens"]
     if layout.needs_segments:
         segments = arrays["segments"]
@@ -267,92 +268,101 @@ class Trainer:
         return self.loader.epoch(epoch, device_put=self.cfg.device_put)
 
     def train_epoch(self, state: dict, epoch: int = 0, start_step: int = 0):
+        """Run one epoch from ``start_step``; returns (state, step index).
+
+        Each step runs under the span ``train/step`` with the phases
+        ``train/realize`` (the data path), ``train/assemble`` (the batch
+        dict; ``train/pad`` and ``train/device_put`` inside it when the host
+        assembles), ``train/dispatch`` (enqueueing the jitted step) and, every
+        ``log_every`` steps, ``train/log`` (the record's read of the loss,
+        which waits for the device).  The last ``train/step`` of an epoch
+        holds only the realize that found the epoch's end.
+        """
         if self._train_step is None:
             self._build_step()
         step_idx = start_step
-        t0 = time.perf_counter()
         emitted = 0
         tokens_seen = 0
-        tracer = obs.default_tracer()
+        last_log = None  # (time, emitted, tokens) at the previous record's sync
         m_steps = obs.counter("train_steps_total", help="optimizer steps run")
         m_tokens = obs.counter("train_tokens_total", help="real tokens trained on")
         m_step_dur = obs.histogram(
             "train_step_duration_seconds",
-            help="wall time of one full train step (realize+pad+put+compute)",
+            help="host time of one train step (realize+assemble+dispatch)",
             unit="seconds",
         )
         step_iter = iter(self._epoch_steps(epoch))
         while True:
-            step_t0 = time.perf_counter()
-            # Realize: pull the next aligned step out of the data path
-            # (admission + protocol rounds + layout, or a prefetch dequeue).
-            loader_step = _timed_phase(
-                "train/realize", "train_realize_seconds_total",
-                "data-path time to the next aligned step",
-                lambda: next(step_iter, None),
-            )
-            if loader_step is None:
-                break
-            batch = assemble_model_batch(loader_step, self.loader.layout)
-
-            def _compute():
-                new_state, metrics = self._train_step(state, batch)
-                if tracer.enabled:
-                    # Async dispatch would end the span at enqueue time;
-                    # only force completion when someone is looking.
-                    jax.block_until_ready(metrics["loss"])
-                return new_state, metrics
-
-            state, metrics = _timed_phase(
-                "train/compute", "train_compute_seconds_total",
-                "jitted train_step time (dispatch; synced when tracing)",
-                _compute,
-            )
-            step_idx += 1
-            emitted += loader_step.metadata.emitted_samples
-            tokens_seen += loader_step.metadata.total_tokens
-            step_dt = time.perf_counter() - step_t0
-            m_steps.inc()
-            m_tokens.inc(loader_step.metadata.total_tokens)
-            m_step_dur.observe(step_dt)
-            tracer.complete(
-                "train/step", step_t0, step_dt, cat="train", step=step_idx
-            )
-            if step_idx % self.cfg.log_every == 0:
-                dt = time.perf_counter() - t0
-                rec = self._publish_log_record(
-                    metrics, loader_step, step_idx, emitted, tokens_seen, dt
-                )
-                self.history.append(rec)
-            if (
-                self.cfg.checkpoint_dir
-                and step_idx % self.cfg.checkpoint_every == 0
-            ):
-                ckpt.save_checkpoint(
-                    self.cfg.checkpoint_dir, step_idx, state,
-                    keep=self.cfg.keep_checkpoints,
-                )
+            with obs.span("train/step", cat="train", step=step_idx + 1):
+                step_t0 = time.perf_counter()
+                # Realize: pull the next aligned step out of the data path
+                # (admission + protocol rounds + layout, or a prefetch dequeue).
+                with _timed_phase(
+                    "train/realize", "train_realize_seconds_total",
+                    "data-path time to the next aligned step",
+                ):
+                    loader_step = next(step_iter, None)
+                if loader_step is None:
+                    break
+                with obs.span("train/assemble", cat="train"):
+                    batch = assemble_model_batch(loader_step, self.loader.layout)
+                # The host's enqueue only: the device's time for the step is
+                # in the profiler trace, and waiting here would change the
+                # schedule being measured.
+                with _timed_phase(
+                    "train/dispatch", "train_dispatch_seconds_total",
+                    "host time to dispatch the jitted train_step",
+                ):
+                    state, metrics = self._train_step(state, batch)
+                step_idx += 1
+                emitted += loader_step.metadata.emitted_samples
+                tokens_seen += loader_step.metadata.total_tokens
+                m_steps.inc()
+                m_tokens.inc(loader_step.metadata.total_tokens)
+                m_step_dur.observe(time.perf_counter() - step_t0)
+                if step_idx % self.cfg.log_every == 0:
+                    with obs.span("train/log", cat="train"):
+                        rec, last_log = self._publish_log_record(
+                            metrics, loader_step, step_idx, emitted, tokens_seen,
+                            last_log,
+                        )
+                    self.history.append(rec)
+                if (
+                    self.cfg.checkpoint_dir
+                    and step_idx % self.cfg.checkpoint_every == 0
+                ):
+                    ckpt.save_checkpoint(
+                        self.cfg.checkpoint_dir, step_idx, state,
+                        keep=self.cfg.keep_checkpoints,
+                    )
             if self.cfg.max_steps and step_idx >= self.cfg.max_steps:
                 break
         return state, step_idx
 
     def _publish_log_record(
         self, metrics, loader_step, step_idx: int, emitted: int,
-        tokens_seen: int, dt: float,
-    ) -> dict:
-        """Publish step metrics to the registry and return the log record.
+        tokens_seen: int, last_log: tuple[float, int, int] | None,
+    ) -> tuple[dict, tuple[float, int, int]]:
+        """Publish step metrics to the registry; return the log record and
+        the (time, emitted, tokens) mark the next record's rates start from.
+
+        Reading the loss waits for this step on the device, so the interval
+        from the previous record's mark is wall time of finished steps, with
+        no compile once every shape has been seen.  The first record of an
+        epoch has no interval: its rates read NaN and the rate gauges keep
+        their last value.
 
         One value set feeds the registry gauges, ``self.history`` and the
         stdout line (:meth:`format_log_line`) — the record is a *view* of the
         same snapshot ``metrics.json`` serializes, not a second bookkeeping
-        path (satellite: no more ad-hoc log dict).
+        path.
         """
+        loss = float(metrics["loss"])
+        now = time.perf_counter()
         values = {
-            "train_loss": float(metrics["loss"]),
+            "train_loss": loss,
             "train_step_tokens": float(metrics["tokens"]),
             "train_grad_norm": float(metrics["grad_norm"]),
-            "train_samples_per_second": emitted / dt if dt > 0 else 0.0,
-            "train_tokens_per_second": tokens_seen / dt if dt > 0 else 0.0,
             "train_batch_padding": loader_step.metadata.padding_fraction,
             "train_device_padding": (
                 1.0 - loader_step.metadata.total_tokens / loader_step.device_tokens
@@ -360,19 +370,24 @@ class Trainer:
                 else 0.0
             ),
         }
+        if last_log is not None and now > last_log[0]:
+            dt = now - last_log[0]
+            values["train_samples_per_second"] = (emitted - last_log[1]) / dt
+            values["train_tokens_per_second"] = (tokens_seen - last_log[2]) / dt
         reg = obs.default_registry()
         for name, value in values.items():
             reg.gauge(name).set(value)
-        return {
+        rec = {
             "step": step_idx,
             "loss": values["train_loss"],
             "tokens": values["train_step_tokens"],
             "grad_norm": values["train_grad_norm"],
             "emitted_samples": emitted,
-            "sam_per_s": values["train_samples_per_second"],
+            "sam_per_s": values.get("train_samples_per_second", math.nan),
             "padding": values["train_batch_padding"],
             "device_padding": values["train_device_padding"],
         }
+        return rec, (now, emitted, tokens_seen)
 
     @staticmethod
     def format_log_line(rec: dict) -> str:

@@ -5,7 +5,8 @@ Contracts under test:
   1. **Registry semantics** — counter monotonicity, gauge last-write,
      histogram explicit-bucket binning, labeled children, kind conflicts;
   2. **Disabled is free** — a disabled registry hands back the one shared
-     NULL sink (no allocation), a disabled tracer the one shared NULL_SPAN;
+     NULL sink (no allocation), a disabled tracer without JAX the one shared
+     NULL_SPAN, and with JAX a span that opens only a profiler annotation;
   3. **Views** — Prometheus text exposition golden, flat() naming;
   4. **Trace** — span nesting by containment, bounded ring with accounted
      drops, Chrome trace-event JSON schema validity;
@@ -17,7 +18,10 @@ Contracts under test:
 from __future__ import annotations
 
 import json
+import os
 import random
+import subprocess
+import sys
 
 import pytest
 
@@ -36,6 +40,7 @@ from repro.obs import (
     RunReporter,
     SpanTracer,
 )
+from repro.obs import trace as trace_mod
 from repro.stream import StreamCheckpoint, StreamExecutor
 
 POLICY = PipelinePolicy()
@@ -183,12 +188,53 @@ class TestRegistry:
 
 
 class TestTracer:
-    def test_disabled_span_is_shared_null(self):
+    def test_disabled_span_is_shared_null(self, monkeypatch):
+        # Without JAX there is no profiler sink either.
+        monkeypatch.setattr(trace_mod, "_profiler_annotation", lambda: None)
         tracer = SpanTracer(enabled=False)
         assert tracer.span("x") is NULL_SPAN
         tracer.complete("x", 0.0, 1.0)
         tracer.instant("x")
         assert tracer.events() == []
+
+    def test_disabled_span_without_profiler_records_nothing(self):
+        tracer = SpanTracer(enabled=False)
+        with tracer.span("outer", cat="t", k=1) as span:
+            span.note(done=True)
+            with tracer.span("inner"):
+                pass
+        assert tracer.events() == [] and tracer.dropped == 0
+
+    def test_span_closes_its_annotation_when_the_body_raises(self):
+        tracer = SpanTracer(enabled=True)
+        with pytest.raises(KeyError):
+            with tracer.span("failing"):
+                raise KeyError("x")
+        (event,) = tracer.events()
+        assert event["name"] == "failing"
+        with tracer.span("after"):  # the thread's annotation stack is whole
+            pass
+        assert [e["name"] for e in tracer.events()] == ["failing", "after"]
+
+    def test_note_attaches_args_at_exit(self):
+        tracer = SpanTracer(enabled=True)
+        with tracer.span("round", cat="t", round=0) as span:
+            span.note(target=4)
+        assert tracer.events()[0]["args"] == {"round": 0, "target": 4}
+
+    def test_obs_imports_and_records_without_jax(self):
+        code = (
+            "import sys; sys.modules['jax'] = None\n"
+            "from repro import obs\n"
+            "t = obs.default_tracer(); t.enable()\n"
+            "with obs.span('a/b') as s:\n"
+            "    s.note(n=1)\n"
+            "assert [e['name'] for e in t.events()] == ['a/b'], t.events()\n"
+        )
+        env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+        proc = subprocess.run([sys.executable, "-c", code], env=env,
+                              capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr
 
     def test_nesting_by_containment(self):
         tracer = SpanTracer(enabled=True)

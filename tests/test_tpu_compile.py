@@ -5,7 +5,8 @@ refuses: block shapes that are not (8, 128)-aligned, SMEM or VMEM overflow.
 These tests compile the main-path kernels at Qwen3-0.6B attention width
 (16 heads, 8 KV heads, head_dim 128, bf16) for a v5e chip that is described,
 not attached, and assert that each program holds a Mosaic kernel
-(``tpu_custom_call``).  No chip is needed; nothing runs.
+(``tpu_custom_call``) named for its pass (``flash_fwd``, ``flash_dq``,
+``flash_dkv``).  No chip is needed; nothing runs.
 
 The topology is described inside a fixture (never at import): only one
 process at a time may load the TPU compiler library.
@@ -15,6 +16,7 @@ from __future__ import annotations
 
 import importlib.util
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,6 +91,17 @@ def _compile_bwd(sharding, grid, b, s) -> str:
     return _compile(sharding, BWD[grid], *_bwd_operands(b, s))
 
 
+def _kernel_names(text: str) -> list[str]:
+    """The name of each Mosaic kernel call of a compiled program, taken from
+    its op_name metadata (``.../flash_fwd/pallas_call``), which the profiler
+    trace shows on the kernel's device events."""
+    return [
+        re.search(r'op_name="[^"]*?([A-Za-z0-9_]+)/pallas_call"', line).group(1)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+
+
 @pytest.mark.parametrize("grid", ["dense", "pruned"])
 def test_forward_compiles(one_chip, grid):
     text = _compile(
@@ -97,12 +110,14 @@ def test_forward_compiles(one_chip, grid):
         *_qkv_seg(1, 4096),
     )
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == ["flash_fwd"]
 
 
 @pytest.mark.parametrize("grid", ["dense", "pruned"])
 def test_backward_compiles(one_chip, grid):
     text = _compile_bwd(one_chip, grid, 1, 4096)
     assert text.count("tpu_custom_call") == 2  # dQ pass + dK/dV pass
+    assert sorted(_kernel_names(text)) == ["flash_dkv", "flash_dq"]
 
 
 def _smoke_packed_shapes() -> set[tuple[int, int]]:
@@ -135,3 +150,6 @@ def test_pruned_backward_splits_rows_to_fit_smem(one_chip):
     SMEM, so the pruned grid runs several row-group calls per pass."""
     text = _compile_bwd(one_chip, "pruned", 16, 16384)
     assert text.count("tpu_custom_call") > 2
+    names = _kernel_names(text)  # every row group's call carries its pass's name
+    assert set(names) == {"flash_dq", "flash_dkv"}
+    assert names.count("flash_dq") == names.count("flash_dkv") > 1

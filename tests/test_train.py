@@ -110,6 +110,105 @@ class TestEndToEnd:
         assert out["segments"][:2, 8:].sum() == 0  # grown region is padding
 
 
+def _host_lines(logdir) -> dict:
+    """Host-thread lines of the newest profiler trace under ``logdir``:
+    line name -> [(event name, start ns, end ns)]."""
+    from jax.profiler import ProfileData
+
+    path = sorted(pathlib.Path(logdir).rglob("*.xplane.pb"))[-1]
+    lines = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                lines[line.name] = [
+                    (e.name, e.start_ns, e.start_ns + e.duration_ns) for e in line.events
+                ]
+    return lines
+
+
+def _packed_trainer(log_every: int, max_steps: int) -> Trainer:
+    cfg = dataclasses.replace(get_smoke_config("qwen3_0_6b"), vocab_size=256)
+    loader = OnlineDynamicLoader(
+        tiny_dataset(), world_size=2,
+        config=OdbConfig(l_max=256, buffer_size=16, prefetch_factor=8, num_workers=2),
+        layout="packed", vocab_size=256,
+    )
+    return Trainer(
+        LM(cfg), loader, OptimizerConfig(),
+        TrainerConfig(log_every=log_every, max_steps=max_steps),
+    )
+
+
+class TestStepSpans:
+    """The step loop's spans reach the profiler trace with the ring off."""
+
+    def test_phases_nest_in_train_step_on_the_loop_thread(self, tmp_path):
+        trainer = _packed_trainer(log_every=3, max_steps=3)
+        state = trainer.init_state(jax.random.PRNGKey(0))
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            trainer.train_epoch(state, epoch=0)
+        finally:
+            jax.profiler.stop_trace()
+        lines = _host_lines(tmp_path)
+        (loop,) = [n for n, evs in lines.items() if any(e[0] == "train/step" for e in evs)]
+        events = lines[loop]
+        phases = {"train/realize", "train/assemble", "train/dispatch"}
+        whole = [
+            (lo, hi) for name, lo, hi in events
+            if name == "train/step"
+            and phases <= {n for n, s, t in events if lo <= s and t <= hi}
+        ]
+        assert len(whole) == 3
+        assert any(name == "train/log" for name, _, _ in events)
+        producers = [n for n, evs in lines.items() if any(e[0] == "prefetch/produce" for e in evs)]
+        assert producers and loop not in producers
+
+    def test_log_rates_cover_the_interval_between_records(self):
+        trainer = _packed_trainer(log_every=1, max_steps=3)
+        trainer.train_epoch(trainer.init_state(jax.random.PRNGKey(0)), epoch=0)
+        rates = [h["sam_per_s"] for h in trainer.history]
+        assert len(rates) == 3 and np.isnan(rates[0])  # no interval before the first
+        assert all(np.isfinite(r) and r > 0 for r in rates[1:]), rates
+        from repro import obs
+
+        flat = obs.default_registry().flat()
+        assert flat["train_samples_per_second"] == rates[-1]
+        assert flat["train_tokens_per_second"] > 0
+        assert "train_dispatch_seconds_total" in flat
+        assert "train_compute_seconds_total" not in flat
+
+    def test_step_hlo_names_the_model_parts(self):
+        """Forward and backward operations carry the part's scope in their
+        HLO metadata, which the device trace shows."""
+        import re
+
+        from repro.train.trainer import make_train_step
+
+        cfg = dataclasses.replace(get_smoke_config("qwen3_0_6b"), vocab_size=256)
+        model = LM(cfg)
+        params = model.init(jax.random.PRNGKey(0))
+        state = {"params": params, "opt": init_opt_state(params, OptimizerConfig())}
+        b, s = 2, 64
+        batch = {
+            "tokens": jnp.zeros((b, s), jnp.int32),
+            "labels": jnp.zeros((b, s), jnp.int32),
+            "loss_mask": jnp.ones((b, s), jnp.float32),
+        }
+        step = jax.jit(make_train_step(model, OptimizerConfig()))
+        ops = re.findall(r'op_name="([^"]*)"', step.lower(state, batch).compile().as_text())
+        for scope in ("attention", "mlp", "lm_loss", "adamw"):
+            pat = re.compile(rf"(?:^|[/(;]){scope}(?=[/);]|$)")
+            scoped = [o for o in ops if pat.search(o)]
+            assert any("transpose(" not in o for o in scoped), scope
+            if scope != "adamw":  # the update follows the gradient: no backward
+                assert any("transpose(" in o for o in scoped), scope
+        assert any("transpose(jvp(lm_loss))" in o for o in ops)
+
+
 class TestCheckpoint:
     def test_roundtrip_and_rotation(self, tmp_path):
         state = {
