@@ -7,9 +7,10 @@ at a time), in this order:
 
   (a) device check — exits non-zero unless JAX's first device is a TPU;
   (b) kernel parity — compiled flash forward and dQ/dK/dV on one packed
-      bf16 input at Qwen3-0.6B attention width, dense and pruned grids,
-      against the float32 reference (kernels/ref.py) under "highest"
-      matmul precision; pruned must equal dense exactly;
+      bf16 input at Qwen3-0.6B attention width, dense and pruned grids, at
+      128 x 128 tiles and at the tiles the model's rule picks for the row
+      (``heuristic_blocks``), against the float32 reference (kernels/ref.py)
+      under "highest" matmul precision; pruned must equal dense exactly;
   (c) packed training — ``repro.launch.train.main`` at the published width
       (28 layers, d_model 1024, vocab 151936), flash attention on the
       pruned grid, a few steps with finite losses and a closed epoch audit;
@@ -78,6 +79,7 @@ def kernel_parity() -> None:
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.kernels.autotune import heuristic_blocks
     from repro.kernels.ops import flash_attention
     from repro.kernels.ref import segment_flash_attention_ref
 
@@ -98,17 +100,18 @@ def kernel_parity() -> None:
     valid = (seg > 0)[:, :, None, None]
     g = jax.random.normal(keys[3], (b, s, h, d), jnp.bfloat16) * valid
 
-    def kernel_run(grid):
-        fn = lambda q_, k_, v_: flash_attention(q_, k_, v_, seg, grid=grid)
+    def kernel_run(grid, blocks):
+        fn = lambda q_, k_, v_: flash_attention(q_, k_, v_, seg, True, *blocks, grid)
         out, vjp = jax.vjp(fn, q, k, v)
         return jax.block_until_ready((out, *vjp(g)))
 
     results = {}
-    for grid in ("dense", "pruned"):
-        t0 = time.perf_counter()
-        results[grid] = [np.asarray(x) for x in kernel_run(grid)]
-        _log(f"kernel grid={grid} fwd+bwd compiled and ran in "
-             f"{time.perf_counter() - t0:.1f}s")
+    for blocks in sorted({(128, 128), heuristic_blocks(s)}):
+        for grid in ("dense", "pruned"):
+            t0 = time.perf_counter()
+            results[grid, blocks] = [np.asarray(x) for x in kernel_run(grid, blocks)]
+            _log(f"kernel grid={grid} blocks={blocks} fwd+bwd compiled and "
+                 f"ran in {time.perf_counter() - t0:.1f}s")
 
     with jax.default_matmul_precision("highest"):
         f32 = lambda x: x.astype(jnp.float32)
@@ -117,7 +120,7 @@ def kernel_parity() -> None:
         ref = [np.asarray(x) for x in jax.block_until_ready((out, *vjp(f32(g))))]
     mask = np.asarray(valid)
     names = ("out", "dq", "dk", "dv")
-    for grid, got in results.items():
+    for (grid, blocks), got in results.items():
         for name, a, r in zip(names, got, ref):
             a = a.astype(np.float32)
             if name == "out":
@@ -125,12 +128,17 @@ def kernel_parity() -> None:
             err = float(np.max(np.abs(a - r)))
             scale = float(np.max(np.abs(r)))
             _check(math.isfinite(err), f"{grid} {name} is finite")
-            _log(f"parity grid={grid} {name}: max_abs_err={err!r} "
-                 f"ref_max_abs={scale!r} rel={err / scale!r} tol={PARITY_TOL}")
-            _check(err <= PARITY_TOL * scale, f"{grid} {name} within tolerance")
-    for name, a, p in zip(names, results["dense"], results["pruned"]):
-        _check(np.array_equal(a, p), f"pruned {name} equals dense bit for bit")
-    _log("parity pruned == dense bit-exact for out, dq, dk, dv")
+            _log(f"parity grid={grid} blocks={blocks} {name}: "
+                 f"max_abs_err={err!r} ref_max_abs={scale!r} "
+                 f"rel={err / scale!r} tol={PARITY_TOL}")
+            _check(err <= PARITY_TOL * scale,
+                   f"{grid} {blocks} {name} within tolerance")
+        if grid == "pruned":
+            for name, a, p in zip(names, results["dense", blocks], got):
+                _check(np.array_equal(a, p),
+                       f"pruned {name} equals dense bit for bit at {blocks}")
+            _log(f"parity pruned == dense bit-exact for out, dq, dk, dv "
+                 f"at {blocks}")
 
 
 def train(label: str, extra: list[str], device):

@@ -12,8 +12,9 @@ The probe runs at trace time (block sizes are static arguments to the
 kernel), on synthetic inputs of the real shape, timing forward + backward
 through the ``flash_attention`` custom-vjp.  When autotuning is off
 (``ArchConfig.attn_autotune = False``, the default) the heuristic schedule
-is used: the largest block ≤ 128 dividing S, the same rule
-``select_block`` applies to ragged shapes.
+is used: 512 x 512 tiles, 512 x 1024 from S = 4096 up, each side the
+largest divisor of S at or under that size (``select_block``), the kv side
+a multiple of 128 or S itself (``kv_block``).
 """
 
 from __future__ import annotations
@@ -37,20 +38,49 @@ DEFAULT_CACHE_PATH = pathlib.Path("artifacts") / "autotune" / "attn_blocks.json"
 _CACHES: dict[str, dict[str, tuple[int, int]]] = {}
 
 
+# The largest tile area whose float32 dK/dV pass fits the v5e's VMEM at
+# head_dim 128: 512 x 1024 compiles, 1024 x 1024 does not.
+MAX_TILE_AREA = 512 * 1024
+# The rule's q tile, and the shortest row at which its kv tile widens to
+# fill MAX_TILE_AREA (the shortest row where the wide tile was measured).
+BLOCK_Q = 512
+WIDE_KV_FROM = 4096
+
+
+def kv_block(s: int, requested: int) -> int:
+    """Largest divisor of ``s`` at or under ``requested`` that Mosaic takes
+    as a kv tile: the kv segment ids are blocked along lanes, so a multiple
+    of 128 or ``s`` itself.  Where there is none, ``select_block(s, 128)``."""
+    if s <= requested:
+        return s
+    for c in range(requested // 128 * 128, 0, -128):
+        if s % c == 0:
+            return c
+    return select_block(s, 128)
+
+
 def heuristic_blocks(s: int) -> tuple[int, int]:
-    """Probe-free default: square blocks at the largest divisor ≤ 128."""
-    b = select_block(s, 128)
-    return b, b
+    """Probe-free default, from a sweep of the pruned passes on a TPU v5e
+    (float32, head_dim 128; PERF.md, section 6): at every row length of the
+    benchmark's windows the largest tiles ran fastest, because a grid step's
+    fixed cost outweighs the masked area a larger tile adds.  A 512-row q
+    tile; a 512-wide kv tile, or ``MAX_TILE_AREA`` wide from
+    ``WIDE_KV_FROM`` up."""
+    bk = MAX_TILE_AREA // BLOCK_Q if s >= WIDE_KV_FROM else BLOCK_Q
+    return select_block(s, BLOCK_Q), kv_block(s, bk)
 
 
 def candidate_blocks(s: int) -> list[tuple[int, int]]:
-    """Candidate (block_q, block_kv) pairs — exact divisors of S only,
-    capped at 128 (the kernel's ``select_block`` cap: larger requests would
-    silently alias the 128 schedule and pollute the persisted cache)."""
-    divs = [d for d in (128, 64, 32) if d <= s and s % d == 0]
+    """Candidate (block_q, block_kv) pairs — exact divisors of S only (a
+    request that does not divide S would resolve to a smaller block and
+    alias another candidate in the persisted cache), at most
+    ``MAX_TILE_AREA`` per tile."""
+    divs = [d for d in (1024, 512, 256, 128, 64, 32) if d <= s and s % d == 0]
     if not divs:
-        divs = [select_block(s, 128)]
-    return sorted({(bq, bk) for bq in divs for bk in divs})
+        divs = [select_block(s, 512)]
+    return sorted(
+        {(bq, bk) for bq in divs for bk in divs if bq * bk <= MAX_TILE_AREA}
+    )
 
 
 def shape_key(

@@ -50,8 +50,9 @@ softmax mass is empty (l == 0, all-padding rows) contribute exactly zero
 gradient because ``p`` is built under the mask.
 
 Block sizes need not divide S: ``select_block`` drops to the largest
-divisor ≤ 128 (ragged sequence cells degrade gracefully instead of
-asserting).
+divisor at or under the request (ragged sequence cells degrade gracefully
+instead of asserting).  The model's default request comes from the row
+length (``kernels.autotune.heuristic_blocks``).
 """
 
 from __future__ import annotations
@@ -73,17 +74,17 @@ _SEG_BIG = 1 << 30  # "no positive segment in this block" sentinel
 SMEM_TABLE_BUDGET = 512 * 1024
 
 
-def select_block(s: int, requested: int, cap: int = 128) -> int:
-    """Largest block ≤ min(requested, cap) that divides ``s``.
+def select_block(s: int, requested: int) -> int:
+    """Largest block ≤ ``requested`` that divides ``s``.
 
     Keeps the kernel grid exact for ragged sequence cells instead of
     asserting ``s % block == 0``.  Divisors that are multiples of 8 (the
     fp32 sublane) are preferred so the compiled TPU path keeps
-    Mosaic-legal tile shapes: 384 → 128, 200 → 40 (not 100), 96 → 96.
-    Shapes with no aligned divisor (e.g. prime S) fall back to the largest
-    divisor of any width — interpret-mode territory.
+    Mosaic-legal tile shapes: (768, 512) → 384, (200, 128) → 40 (not 100),
+    (96, 128) → 96.  Shapes with no aligned divisor (e.g. prime S) fall
+    back to the largest divisor of any width — interpret-mode territory.
     """
-    b = min(requested, cap, s)
+    b = min(requested, s)
     unaligned = 1
     for c in range(b, 0, -1):
         if s % c:

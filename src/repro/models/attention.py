@@ -160,8 +160,8 @@ def _flash_blocks(
     from repro.kernels.flash_attention import select_block
 
     if cfg.attn_block_q or cfg.attn_block_kv:
-        # Partial pins are honored: the unset side falls back to the
-        # heuristic width rather than dropping the explicit one.
+        # Partial pins are honored: the unset side falls back to 128
+        # rather than dropping the explicit one.
         return (
             select_block(s, cfg.attn_block_q or 128),
             select_block(s, cfg.attn_block_kv or 128),

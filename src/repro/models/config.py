@@ -81,7 +81,9 @@ class ArchConfig:
     # every variant resolves to dense.
     attn_grid: Literal["dense", "pruned", "auto"] = "auto"
     # Flash kernel block schedule; 0 = pick automatically (measured probe
-    # when attn_autotune, else the largest divisor of S ≤ 128).
+    # when attn_autotune, else kernels.autotune.heuristic_blocks: 512 x 512,
+    # 512 x 1024 from S = 4096 up, each the largest divisor of S under it,
+    # the kv side a multiple of 128).
     attn_block_q: int = 0
     attn_block_kv: int = 0
     # Measured (block_q, block_kv) probe per shape cell, cached under

@@ -8,6 +8,7 @@ on packed batches with GQA, segments, and fully-masked padding rows (the
 l == 0 denominator)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -122,11 +123,17 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-4, rtol=1e-4)
 
 
-def packed_test_segments(b: int, s: int):
+# Document bounds as fractions of the row: three documents and a padding
+# tail, or nine short ones, so that a 256-wide tile holds several.
+FEW_DOCS = (0.0, 0.3, 0.55, 0.9)
+MANY_DOCS = (0.0, 0.04, 0.13, 0.15, 0.3, 0.38, 0.55, 0.6, 0.74, 0.9)
+
+
+def packed_test_segments(b: int, s: int, docs=FEW_DOCS):
     """Packed rows exercising every backward edge: multiple segments per
     row, a padding tail, and one fully-masked row (l == 0 everywhere)."""
     seg = np.zeros((b, s), np.int32)
-    bounds = [0, int(s * 0.3), int(s * 0.55), int(s * 0.9)]
+    bounds = [int(s * f) for f in docs]
     for i in range(b - 1):
         for j in range(len(bounds) - 1):
             seg[i, bounds[j] : bounds[j + 1]] = j + 1
@@ -151,13 +158,21 @@ class TestFlashBackward:
 
         return loss_flash, loss_ref
 
-    @pytest.mark.parametrize("shape", [(2, 256, 4, 2, 32), (2, 128, 8, 1, 64)])
+    @pytest.mark.parametrize("shape", [
+        (2, 256, 4, 2, 32), (2, 128, 8, 1, 64),
+        # tiles above 128, several documents per tile
+        (2, 1024, 4, 2, 32, (256, 256)), (2, 1024, 4, 2, 32, (256, 512)),
+        # the rule's pairs
+        (2, 1024, 4, 2, 32, (512, 512)), (2, 2048, 4, 2, 32, (512, 1024)),
+    ])
     def test_segment_grads_vs_ref(self, shape):
         """GQA + segments + an all-padding row (l == 0 denominator)."""
-        b, s, h, kv, d = shape
+        b, s, h, kv, d, *blocks = shape
+        bq, bk = blocks[0] if blocks else (64, 64)
         q, k, v = make_qkv(jax.random.PRNGKey(7), b, s, h, kv, d, jnp.float32)
-        seg = packed_test_segments(b, s)
+        seg = packed_test_segments(b, s, MANY_DOCS if blocks else FEW_DOCS)
         loss_flash, loss_ref = self._masked_losses(seg)
+        loss_flash = functools.partial(loss_flash, bq=bq, bk=bk)
         g = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
         for a, b_ in zip(g, gr):
@@ -328,6 +343,44 @@ class TestKernelRouting:
         with pytest.raises(ValueError, match="flash"):
             LM(mla)
 
+    @pytest.mark.parametrize("s, blocks", [
+        # every row length of the benchmark cells' windows
+        (512, (512, 512)), (768, (384, 384)), (1024, (512, 512)),
+        (1536, (512, 512)), (2048, (512, 512)), (3072, (512, 512)),
+        (4096, (512, 1024)), (6144, (512, 1024)), (8192, (512, 1024)),
+        # rows of other l_max grids, where 512 and 1024 do not divide S
+        (640, (320, 128)), (1280, (320, 256)), (1792, (448, 256)),
+        (1920, (480, 384)), (2176, (272, 128)), (7680, (512, 768)),
+        # ragged and short rows
+        (200, (200, 200)), (128, (128, 128)), (4104, (456, 72)),
+    ])
+    def test_heuristic_blocks_rule(self, s, blocks):
+        from repro.kernels.autotune import MAX_TILE_AREA, heuristic_blocks
+
+        bq, bk = heuristic_blocks(s)
+        assert (bq, bk) == blocks
+        assert resolve_blocks(s, bq, bk) == blocks
+        assert bq * bk <= MAX_TILE_AREA
+        # Mosaic's (8, 128) rule, wherever S has a divisor that keeps it
+        assert bq % 8 == 0 or bq == s
+        assert bk % 128 == 0 or bk == s or s % 128
+
+    def test_flash_blocks_pins_and_rule(self):
+        """Explicit pins win (above 128 too); a partial pin takes 128 on its
+        unset side; no pin gives the rule."""
+        from repro.configs import get_smoke_config
+        from repro.models.attention import _flash_blocks
+
+        cfg = get_smoke_config("qwen3_0_6b")
+        pick = lambda **kw: _flash_blocks(
+            dataclasses.replace(cfg, **kw), 8192, 1, 4, 2, 32, jnp.float32, True
+        )
+        assert pick() == (512, 1024)
+        assert pick(attn_block_q=128, attn_block_kv=128) == (128, 128)
+        assert pick(attn_block_q=256, attn_block_kv=2048) == (256, 2048)
+        assert pick(attn_block_q=64) == (64, 128)
+        assert pick(attn_block_kv=512) == (128, 512)
+
     def test_autotune_blocks_cached_and_valid(self, tmp_path):
         from repro.kernels.autotune import autotune_blocks, candidate_blocks
 
@@ -349,10 +402,17 @@ class TestPrunedGrid:
     """Scalar-prefetch grid (DESIGN.md §17): DMA-level pruning must change
     the fetch census, never the numbers — bit-exact vs the dense grid."""
 
-    def _packed(self, key, b=2, s=256, h=4, kv=2, d=32):
+    def _packed(self, key, b=2, s=256, h=4, kv=2, d=32, docs=FEW_DOCS):
         q, k, v = make_qkv(key, b, s, h, kv, d, jnp.float32)
-        seg = packed_test_segments(b, s)  # GQA + pad tail + all-padding row
+        seg = packed_test_segments(b, s, docs)  # GQA + pad tail + all-padding row
         return q, k, v, seg
+
+    def _packed_for(self, key, blocks):
+        """Tiles above 128 run on rows of nine documents, 1,024 tokens or
+        two of the widest tile."""
+        if max(blocks) <= 128:
+            return self._packed(key)
+        return self._packed(key, s=max(1024, 2 * max(blocks)), docs=MANY_DOCS)
 
     def test_liveness_tables_match_tile_census(self):
         from repro.kernels.liveness import build_liveness_tables
@@ -375,10 +435,14 @@ class TestPrunedGrid:
                 else:
                     assert np.all(row == 0)
 
-    @pytest.mark.parametrize("blocks", [(64, 64), (128, 32), (128, 128)])
+    @pytest.mark.parametrize(
+        "blocks",
+        [(64, 64), (128, 32), (128, 128), (256, 256), (256, 512), (512, 512),
+         (512, 1024)],
+    )
     def test_pruned_fwd_bitexact(self, blocks):
         bq, bk = blocks
-        q, k, v, seg = self._packed(jax.random.PRNGKey(20))
+        q, k, v, seg = self._packed_for(jax.random.PRNGKey(20), blocks)
         dense = flash_attention(q, k, v, seg, True, bq, bk, grid="dense")
         pruned = flash_attention(q, k, v, seg, True, bq, bk, grid="pruned")
         assert np.array_equal(np.asarray(dense), np.asarray(pruned))
@@ -394,13 +458,16 @@ class TestPrunedGrid:
         pruned = flash_attention(q, k, v, seg, grid="pruned")
         assert np.array_equal(np.asarray(dense), np.asarray(pruned))
 
-    def test_pruned_grads_bitexact(self):
-        q, k, v, seg = self._packed(jax.random.PRNGKey(22))
+    @pytest.mark.parametrize(
+        "blocks", [(64, 64), (256, 256), (256, 512), (512, 512), (512, 1024)]
+    )
+    def test_pruned_grads_bitexact(self, blocks):
+        q, k, v, seg = self._packed_for(jax.random.PRNGKey(22), blocks)
         valid = jnp.asarray((np.asarray(seg) > 0)[:, :, None, None], jnp.float32)
 
         def loss(grid):
             def f(q, k, v):
-                out = flash_attention(q, k, v, seg, True, 64, 64, grid=grid)
+                out = flash_attention(q, k, v, seg, True, *blocks, grid=grid)
                 return jnp.sum((out.astype(jnp.float32) * valid) ** 2)
 
             return f
@@ -484,6 +551,17 @@ class TestPrunedGrid:
                 q, k, v, seg, block_q=15, block_kv=15,
                 interpret=True, expect_resolved=True,
             )
+
+    @pytest.mark.parametrize("s", [200, 768, 1024, 6144, 8192])
+    def test_resolve_blocks_fixed_point_above_128(self, s):
+        """Requests above 128 are honoured where they divide S, and one
+        resolution is a fixed point, so the passes never re-resolve."""
+        for req in [(256, 256), (512, 512), (512, 1024), (1024, 512), (4096, 4096)]:
+            r = resolve_blocks(s, *req)
+            assert resolve_blocks(s, *r) == r
+            assert all(s % x == 0 and x <= max(y, 1) for x, y in zip(r, req))
+            if s % req[0] == 0 and s % req[1] == 0:
+                assert r == req
 
     def test_autotune_rekeyed_by_grid(self, tmp_path):
         from repro.kernels.autotune import autotune_blocks, shape_key

@@ -6,7 +6,9 @@ These tests compile the main-path kernels at Qwen3-0.6B attention width
 (16 heads, 8 KV heads, head_dim 128, bf16) for a v5e chip that is described,
 not attached, and assert that each program holds a Mosaic kernel
 (``tpu_custom_call``) named for its pass (``flash_fwd``, ``flash_dq``,
-``flash_dkv``).  No chip is needed; nothing runs.
+``flash_dkv``); and, in float32 at the tiles the model's block rule picks,
+at the widest row of each benchmark cell and at a row that 512 does not
+divide.  No chip is needed; nothing runs.
 
 The topology is described inside a fixture (never at import): only one
 process at a time may load the TPU compiler library.
@@ -118,6 +120,38 @@ def test_backward_compiles(one_chip, grid):
     text = _compile_bwd(one_chip, grid, 1, 4096)
     assert text.count("tpu_custom_call") == 2  # dQ pass + dK/dV pass
     assert sorted(_kernel_names(text)) == ["flash_dkv", "flash_dq"]
+
+
+@pytest.mark.parametrize(
+    "b, s, kv", [(1, 8192, 16), (2, 2048, 8), (2, 1280, 8)]
+)
+def test_pruned_compiles_at_rule_blocks_f32(one_chip, b, s, kv):
+    """The tiles the model's rule picks (512 x 1024 at 8k rows, 512 x 512 at
+    2k) compile in float32 for both passes, at the widest row of each
+    benchmark cell: OLMo-1B's 16/16 heads and Qwen3-0.6B's 16/8; and at a
+    row that 512 does not divide (1280: 320 x 256, a lane-wide kv tile)."""
+    from repro.kernels.autotune import heuristic_blocks
+
+    bq, bk = heuristic_blocks(s)
+    f32 = jnp.float32
+    q, kv_ = ((b, s, H, D), f32), ((b, s, kv, D), f32)
+    seg = ((b, s), jnp.int32)
+    fwd = _compile(
+        one_chip,
+        lambda q, k, v, seg: fa.segment_flash_attention_pruned(
+            q, k, v, seg, block_q=bq, block_kv=bk, return_residuals=True
+        ),
+        q, kv_, kv_, seg,
+    )
+    assert _kernel_names(fwd) == ["flash_fwd"]
+    bwd = _compile(
+        one_chip,
+        lambda *a: fa.segment_flash_attention_bwd_pruned(
+            *a, block_q=bq, block_kv=bk
+        ),
+        q, kv_, kv_, seg, q, ((b, s, H), f32), q,
+    )
+    assert sorted(_kernel_names(bwd)) == ["flash_dkv", "flash_dq"]
 
 
 def _smoke_packed_shapes() -> set[tuple[int, int]]:
