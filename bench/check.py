@@ -1,8 +1,8 @@
 """The comparison that decides `correct`.
 
 Each number compared is a gap between what the timed path produced and the
-plain reference (``reference.py``), and each has a limit of its own in
-``bench/limits/<cell>.json``:
+plain reference the configuration names (``bench/references/``), and each
+has a limit of its own in ``bench/limits/<cell>.json``:
 
   loss_gap     worst of the first three steps: |loss - ref| / ref
   grad1_gap    worst leaf of the first gradient as the optimizer takes it:

@@ -4,9 +4,14 @@ Counts from the configuration's sizes (Hugging Face keys) and the documents
 of a step, never from what the program runs: recomputation and padding are
 not counted.  Any other architecture (latent attention, state-space layers,
 experts, windowed or linear layers) is refused, not guessed.
+
+``KERNELS`` names the kernel families whose work ``kernel_work`` counts:
+each family's pattern of the kernel names its calls carry in the trace.
 """
 
 from __future__ import annotations
+
+KERNELS = {"flash": "flash_fwd|flash_dq|flash_dkv"}  # the Pallas flash passes
 
 _FOREIGN = (
     "num_experts", "num_local_experts", "n_routed_experts", "moe_intermediate_size",
@@ -61,3 +66,8 @@ def flash_work(config: dict, docs, shape, elem_bytes: int) -> tuple[float, float
     ops = 12.0 * dh * h * causal_pairs(docs) * layers
     per_layer = elem_bytes * rows * seq * dh * (4 * h + 4 * kv) + 4 * rows * seq * h
     return ops, float(per_layer * layers), layers
+
+
+def kernel_work(config: dict, docs, shape, elem_bytes: int) -> dict:
+    """(operations, bytes) of each family of ``KERNELS`` in one step."""
+    return {"flash": flash_work(config, docs, shape, elem_bytes)[:2]}

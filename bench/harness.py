@@ -23,7 +23,12 @@ The window then continues the same epoch through ``train_epoch`` until
 ``seconds`` have passed, and closes on ``block_until_ready`` of the state it
 returns.  After it: the device's peak memory is read, the loader's epoch is
 closed (its DGAP audit drains), the program's state is freed and the plain
-reference follows the three steps for the comparison.
+reference that the configuration names (``bench/references/<name>.py``)
+follows the three steps for the comparison.
+
+The profiler trace of a ``--trace 1`` run is reduced with the kernel
+families that the configuration's flops module declares (``KERNELS``), and
+the work of each family per step (``kernel_work``).
 """
 
 from __future__ import annotations
@@ -41,14 +46,11 @@ import numpy as np
 
 import cell as cells
 import check
-import reference
 import xplane
 
 PEAKS = cells.CODE_DIR / "peaks.json"
 TRACE_DIR = ".bench_trace"  # under the checkout, git-ignored
-# The Pallas flash kernels (forward, dQ, dK/dV): the only Mosaic kernels of the
-# train step; their calls carry no name of their own in the trace yet.
-FLASH_KERNELS = r'custom_call_target="tpu_custom_call"'
+MOSAIC = r'custom_call_target="tpu_custom_call"'  # a Pallas kernel's call
 TRAIN_PROGRAM = r"train_step"
 TRACE_FROM = 2 / 3  # the profiler covers the window's last third
 
@@ -137,41 +139,68 @@ def segment_lengths(step) -> list[int]:
     return out
 
 
-def _program_leaf_names(path) -> tuple[str, bool]:
-    """The comparison's leaf name for a path of the program's parameter tree,
-    and whether the leaf stacks the layers on its first axis."""
-    keys = [getattr(k, "key", getattr(k, "name", str(k))) for k in path]
-    if keys[0] != "stack":
-        return keys[0], False
-    name = keys[-1]
-    if name == "scale":
-        name = {"norm_mixer": "attn_norm", "norm_ffn": "mlp_norm"}[keys[-2]]
-    return name, True
+# Groups of a layer whose leaves the comparison names by their own key (the
+# mixer's ``wq``, the dense MLP's ``w_in``), and the names of its norms.
+_FLAT_GROUPS = ("mixer", "mlp")
+_NORM_NAMES = {"norm_mixer": "attn_norm", "norm_ffn": "mlp_norm"}
 
 
-def leaf_norms(tree, minus=None) -> dict:
+def _key(k):
+    for attr in ("key", "idx", "name"):
+        if hasattr(k, attr):
+            return getattr(k, attr)
+    return str(k)
+
+
+def _inner_name(keys) -> str:
+    if len(keys) == 2 and keys[1] == "scale":  # a norm: by the part it comes before
+        return _NORM_NAMES.get(keys[0], keys[0])
+    if keys[0] in _FLAT_GROUPS:
+        keys = keys[1:]
+    return ".".join(str(k) for k in keys)
+
+
+def leaf_names(path, plan) -> list[str]:
+    """The comparison's names for a leaf of the program's parameter tree
+    (``reference.py`` names the reference's alike): one name, or one per
+    unit for a leaf of the scanned stack, whose first axis runs over units.
+
+    A layer's leaf is ``layerNN.<path inside the layer>``, the layer's index
+    in the model from ``plan`` (``models.blocks.stack_plan``): the unrolled
+    prefix's layer ``i`` is ``plan.prefix_layers[i]``, unit ``u``'s
+    sub-layer ``j`` is ``plan.unit_layers[u][j]``.
+    """
+    keys = [_key(k) for k in path]
+    if keys[0] == "prefix":  # prefix, i, sub0, ...
+        return [f"layer{plan.prefix_layers[keys[1]]:02d}.{_inner_name(keys[3:])}"]
+    if keys[0] == "stack":  # stack, sub<j>, ...
+        j = int(keys[1].removeprefix("sub"))
+        return [f"layer{unit[j]:02d}.{_inner_name(keys[2:])}" for unit in plan.unit_layers]
+    return [_inner_name(keys)]
+
+
+def leaf_norms(tree, plan, minus=None) -> dict:
     """Norm of each leaf of the program's parameter-shaped ``tree`` (of
-    ``tree - minus``), each layer of a stacked leaf apart."""
+    ``tree - minus``), each layer apart, by ``leaf_names``."""
 
     def norms(t, m):
         diff = t if m is None else jax.tree.map(lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), t, m)
         out = {}
         for path, x in jax.tree_util.tree_flatten_with_path(diff)[0]:
-            name, stacked = _program_leaf_names(path)
             sq = jnp.square(x.astype(jnp.float32))
-            if stacked:
-                out[name] = jnp.sqrt(jnp.sum(sq.reshape(sq.shape[0], -1), axis=1))
+            if _key(path[0]) == "stack":
+                out[jax.tree_util.keystr(path)] = jnp.sqrt(jnp.sum(sq.reshape(sq.shape[0], -1), axis=1))
             else:
-                out[name] = jnp.sqrt(jnp.sum(sq))
+                out[jax.tree_util.keystr(path)] = jnp.sqrt(jnp.sum(sq))
         return out
 
     got = jax.device_get(jax.jit(norms)(tree, minus))
     flat = {}
-    for name, v in got.items():
-        if np.ndim(v):
-            flat.update({f"layer{l:02d}.{name}": float(x) for l, x in enumerate(v)})
-        else:
-            flat[name] = float(v)
+    for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        for name, x in zip(leaf_names(path, plan), np.atleast_1d(got[jax.tree_util.keystr(path)])):
+            if name in flat:
+                raise ValueError(f"two leaves of the program's tree are named {name!r}")
+            flat[name] = float(x)
     return flat
 
 
@@ -179,7 +208,10 @@ class Probe:
     """Reads the first three steps' outputs around the trainer's jitted step."""
 
     def __init__(self, trainer, key, beta1: float):
+        from repro.models.blocks import stack_plan
+
         self.trainer, self.key, self.beta1 = trainer, key, beta1
+        self.plan = stack_plan(trainer.model.cfg)
         self.step = trainer._train_step
         self.losses = []
         self.grad1 = self.change = None
@@ -190,11 +222,11 @@ class Probe:
         new_state, metrics = self.step(state, batch)
         self.losses.append(metrics["loss"])
         if len(self.losses) == 1:
-            m = leaf_norms(new_state["opt"]["m"])
+            m = leaf_norms(new_state["opt"]["m"], self.plan)
             self.grad1 = {k: v / (1.0 - self.beta1) for k, v in m.items()}
         if len(self.losses) == 3:
             start = self._init(self.key)
-            self.change = leaf_norms(new_state["params"], start)
+            self.change = leaf_norms(new_state["params"], self.plan, start)
             del start
         return new_state, metrics
 
@@ -233,7 +265,7 @@ def build_trainer(cell):
     from repro.models import LM
     from repro.train.trainer import Trainer, TrainerConfig
 
-    arch = cells.arch_config(cell.config)
+    arch = cells.arch_config(cell.config, cell.root)
     loader = cells.make_loader(cell.traffic, arch.vocab_size)
     opt = cells.optimizer_config(cell.config)
     trainer = Trainer(LM(arch), loader, opt, TrainerConfig(checkpoint_every=20, log_every=5))
@@ -268,7 +300,7 @@ def verdict(cell, seed: int, program: dict, probed, audit, packed: bool, **ref_k
     """The numbers compared with the plain reference, each beside its limit,
     and the reference's readings."""
     batches = [{k: a[k] for k in ("tokens", "segments", "loss_mask")} for a, _ in probed]
-    ref = reference.train3(cell.config, seed, batches, **ref_kw)
+    ref = cell.module("references", cell.config["reference"]).train3(cell.config, seed, batches, **ref_kw)
     layout_bad = sum(check.layout_violations(a, md, packed) for a, md in probed)
     eta = float(audit.eta_identity + audit.eta_quota)
     compared = check.compare(program, ref, layout_bad, eta, cell.limits)
@@ -400,19 +432,31 @@ def _listen_compiles(sink: list) -> None:
     jax.monitoring.register_event_duration_secs_listener(on_event)
 
 
+def kernel_patterns(families: dict) -> dict:
+    """Each kernel family's pattern of kernel names (``flash_fwd|flash_dq``)
+    as a pattern over the trace's operations: a Mosaic call whose HLO
+    instruction the pattern names (``%flash_fwd.3 = ...``)."""
+    return {f: rf"(?s)^%?(?:{p})(?:\.\S*)? = .*{MOSAIC}" for f, p in families.items()}
+
+
 def _read_trace(logdir: pathlib.Path, cell, arch, kept) -> dict:
-    """Device numbers of the traced window and the work its steps required."""
+    """Device numbers of the traced window and the work its steps required:
+    the model's operations (``step_flops``), and the operations and bytes of
+    each kernel family the flops module declares (``KERNELS``,
+    ``kernel_work``); a module that declares none counts no kernel."""
     paths = sorted(logdir.rglob("*.xplane.pb"))
     if not paths:
         raise FileNotFoundError(f"the profiler wrote no trace under {logdir}")
-    red = xplane.reduce(xplane.load(paths[-1]), kernels={"flash": FLASH_KERNELS}, module=TRAIN_PROGRAM)
-    flops_mod = cells.load_module("flops", cell.config["flops"])
+    flops_mod = cell.module("flops", cell.config["flops"])
+    families = getattr(flops_mod, "KERNELS", {})
+    red = xplane.reduce(xplane.load(paths[-1]), kernels=kernel_patterns(families), module=TRAIN_PROGRAM)
     docs = [segment_lengths(s) for s in kept]
     shapes = [step_shape(s) for s in kept]
     elem = np.dtype(arch.dtype).itemsize
+    red["path"] = str(paths[-1])
     red["steps"] = len(kept)
     red["model_flops"] = sum(flops_mod.step_flops(cell.config, d) for d in docs)
-    red["flash_work"] = [
-        flops_mod.flash_work(cell.config, d, shp, elem)[:2] for d, shp in zip(docs, shapes)
-    ]
+    red["kernel_work"] = [
+        flops_mod.kernel_work(cell.config, d, shp, elem) for d, shp in zip(docs, shapes)
+    ] if families else []
     return red

@@ -5,4 +5,4 @@ import named
 
 
 def read(run: dict, peaks: dict):
-    return named.named_ms(run, "flash_dq", "flash_dkv")
+    return named.kernel_ms(run, "flash_dq", "flash_dkv")

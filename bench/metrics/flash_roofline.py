@@ -9,13 +9,8 @@ operations over the bf16 peak and its bytes over the HBM bandwidth.  Nothing
 to read where no flash kernel ran (the dense layout routes attention to XLA).
 """
 
+import named
+
 
 def read(run: dict, peaks: dict):
-    tr = run.get("trace")
-    if not tr or not tr["kernel_s"].get("flash"):
-        return None
-    least = sum(
-        max(ops / peaks["bf16_flops_per_s"], nbytes / peaks["hbm_bytes_per_s"])
-        for ops, nbytes in tr["flash_work"]
-    )
-    return 100.0 * least / tr["kernel_s"]["flash"]
+    return named.roofline(run, peaks, "flash")

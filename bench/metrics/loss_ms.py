@@ -6,4 +6,4 @@ import named
 
 
 def read(run: dict, peaks: dict):
-    return named.named_ms(run, "lm_loss")
+    return named.scope_ms(run, "lm_loss")

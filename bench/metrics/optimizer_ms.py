@@ -5,4 +5,4 @@ import named
 
 
 def read(run: dict, peaks: dict):
-    return named.named_ms(run, "adamw")
+    return named.scope_ms(run, "adamw")

@@ -1,18 +1,19 @@
-"""The traced window by the program's own names: device time of the flash
-passes and the model's parts, and each idle gap by the span the step loop
+"""The traced window by the program's own names: device time of any kernel
+or scope a reader asks for, and each idle gap by the span the step loop
 was in.
 
 It reads the profiler trace of the run's window that ``harness.run`` writes
-under ``<checkout>/.bench_trace/<cell>/``, over the same window
-(``xplane.window_bounds``), and adds what the program names in it:
+under ``<checkout>/.bench_trace/<cell>/`` (the run's ``trace["path"]``),
+over the same window (``xplane.window_bounds``), and adds what the program
+names in it:
 
-  named_s         device time of the operations that carry a label: a
-                  flash pass's kernel name (``flash_fwd``, ``flash_dq``,
-                  ``flash_dkv``) in the operation's HLO text, or a model
-                  part's scope (``attention``, ``mlp``, ``lm_loss``,
-                  ``adamw``; forward and backward alike) in its op name;
-                  labels that name no operation are left out; loops and
-                  calls, which hold other operations, are not counted
+  op_s            device time of each distinct operation, by its HLO text
+                  and op name; loops and calls, which hold other
+                  operations, are not counted.  ``labelled_s`` sums it by
+                  label: a kernel name in the operation's HLO text
+                  (``flash_fwd``), or a scope in its op name (``adamw``;
+                  forward and backward alike); ``kernel_ms`` and ``scope_ms``
+                  read any label a reader asks for
   idle_by_span    each idle gap between device operations on the first
                   device, by the innermost harness (``bench/``) or program
                   (``train/``) span open at its midpoint on the step-loop
@@ -37,20 +38,20 @@ import re
 
 import xplane
 
-TRACE_ROOT = pathlib.Path(__file__).resolve().parent.parent / ".bench_trace"
 LOOP_MARKS = (xplane.WINDOW, "train/step")  # what finds the step-loop thread
 LOOP_PREFIXES = ("bench/", "train/")  # spans that may name a gap
 REALIZE = "train/realize"
 OTHER = "host: other"
 SHORT = "device: between ops (< 50 us)"
 
+
 # A kernel name matches as a whole word of an operation's HLO text
 # (``%flash_fwd.3 = ...``); a scope as a whole word of its op name
 # (``jit(train_step)/adamw/mul``, ``transpose(jvp(lm_loss))/dot_general``),
 # never inside a longer name or a quoted parameter path.
-KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
-SCOPES = ("attention", "mlp", "lm_loss", "adamw")
-LABELS = {n: re.compile(rf"(?<![\w']){n}(?![\w'])") for n in KERNELS + SCOPES}
+@functools.lru_cache(maxsize=None)
+def _label(name: str) -> re.Pattern:
+    return re.compile(rf"(?<![\w']){re.escape(name)}(?![\w'])")
 
 
 def from_profile(data) -> tuple[list[xplane.Plane], list[list[xplane.Event]]]:
@@ -153,15 +154,14 @@ def loop_line(host_lines) -> list[xplane.Event]:
 
 
 def reduce(devices, host_lines, op_names: dict) -> dict:
-    """``named_s``, ``idle_by_span`` and ``realize_idle_s`` of the window; a
-    kernel name is looked for in an operation's text, a scope in its op name
-    (``op_names``, from ``device_op_names``)."""
+    """``op_s``, ``idle_by_span`` and ``realize_idle_s`` of the window; an
+    operation's op name comes from ``op_names`` (``device_op_names``)."""
     if not devices:
         raise ValueError("the trace holds no device plane with an 'XLA Ops' line")
     loop = loop_line(host_lines)
     bench = [e for line in host_lines for e in line if e.name.startswith("bench/")]
     lo, hi = xplane.window_bounds(bench, devices)
-    named_ns = collections.Counter()
+    op_ns = collections.Counter()
     first = []
     for i, plane in enumerate(devices):
         for e in plane.lines["XLA Ops"]:
@@ -172,11 +172,7 @@ def reduce(devices, host_lines, op_names: dict) -> dict:
                 first.append((s, t))
             if xplane.op_kind(e.name) in xplane._CONTAINERS:
                 continue
-            op_name = op_names.get(e.name, "")
-            for label, pat in LABELS.items():
-                text = e.text if label in KERNELS else op_name
-                if label in text and pat.search(text):
-                    named_ns[label] += t - s
+            op_ns[e.text, op_names.get(e.name, "")] += t - s
     spans = [
         e for e in loop
         if e.name != xplane.WINDOW and e.name.startswith(LOOP_PREFIXES)
@@ -200,10 +196,27 @@ def reduce(devices, host_lines, op_names: dict) -> dict:
     n = len(devices)
     return {
         "window_s": (hi - lo) / 1e9,
-        "named_s": {k: v / n / 1e9 for k, v in named_ns.items()},
+        "op_s": {k: v / n / 1e9 for k, v in op_ns.items()},
         "idle_by_span": [[k, v / 1e9] for k, v in gaps.most_common()],
         "realize_idle_s": realize_ns / 1e9 if realize else None,
     }
+
+
+def labelled_s(red: dict, kernels=(), scopes=()) -> dict:
+    """Device time of the operations that carry each label: a name of
+    ``kernels`` in an operation's HLO text, one of ``scopes`` in its op
+    name; labels that name no operation are left out."""
+    out = {}
+    for label, in_text in [(k, True) for k in kernels] + [(k, False) for k in scopes]:
+        pat = _label(label)
+        found = []
+        for (text, op_name), sec in red["op_s"].items():
+            where = text if in_text else op_name
+            if label in where and pat.search(where):
+                found.append(sec)
+        if found:
+            out[label] = sum(found)
+    return out
 
 
 @functools.lru_cache(maxsize=1)
@@ -215,15 +228,12 @@ def _reduce_file(path: str, mtime_ns: int) -> dict:
 
 
 def for_run(run: dict) -> dict | None:
-    """The reduction of the run's trace: the newest one under
-    ``TRACE_ROOT``, which the harness wrote for this run; None for an
+    """The reduction of the run's trace, cached per trace file; None for an
     untraced run."""
-    if not run.get("trace"):
+    path = (run.get("trace") or {}).get("path")
+    if path is None:
         return None
-    paths = sorted(TRACE_ROOT.rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime_ns)
-    if not paths:
-        return None
-    return _reduce_file(str(paths[-1]), paths[-1].stat().st_mtime_ns)
+    return _reduce_file(path, pathlib.Path(path).stat().st_mtime_ns)
 
 
 def per_step_ms(run: dict, seconds: float | None) -> float | None:
@@ -234,11 +244,37 @@ def per_step_ms(run: dict, seconds: float | None) -> float | None:
     return 1000.0 * seconds / steps
 
 
-def named_ms(run: dict, *labels: str) -> float | None:
-    """Device time per traced step of the operations carrying any of
-    ``labels``; None where none of them names an operation."""
+def _labelled_ms(run: dict, kernels=(), scopes=()) -> float | None:
     red = for_run(run)
     if red is None:
         return None
-    found = [red["named_s"][k] for k in labels if k in red["named_s"]]
-    return per_step_ms(run, sum(found)) if found else None
+    found = labelled_s(red, kernels, scopes)
+    return per_step_ms(run, sum(found.values())) if found else None
+
+
+def kernel_ms(run: dict, *names: str) -> float | None:
+    """Device time per traced step of the operations whose HLO text holds
+    any of the kernel ``names`` (``"flash_fwd"``, ``"expert_gmm"``); None
+    where none of them names an operation."""
+    return _labelled_ms(run, kernels=names)
+
+
+def scope_ms(run: dict, *names: str) -> float | None:
+    """As ``kernel_ms``, for scopes in the op name (``"adamw"``, ``"moe"``)."""
+    return _labelled_ms(run, scopes=names)
+
+
+def roofline(run: dict, peaks: dict, family: str) -> float | None:
+    """Roofline share of a kernel family of the flops module: the least time
+    its work (``trace["kernel_work"]``) allows on the chip, the larger of its
+    operations over the bf16 peak and its bytes over the HBM bandwidth per
+    step, over the family's device time (``trace["kernel_s"]``); None where
+    no kernel of the family ran."""
+    tr = run.get("trace")
+    if not tr or not tr["kernel_s"].get(family):
+        return None
+    least = sum(
+        max(work[family][0] / peaks["bf16_flops_per_s"], work[family][1] / peaks["hbm_bytes_per_s"])
+        for work in tr["kernel_work"]
+    )
+    return 100.0 * least / tr["kernel_s"][family]

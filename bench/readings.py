@@ -31,7 +31,6 @@ sys.path.insert(0, str(ROOT / "src"))
 import cell as cells  # noqa: E402
 import check  # noqa: E402
 import harness  # noqa: E402
-import reference  # noqa: E402
 
 
 def _numbers(compared: dict) -> dict:
@@ -69,6 +68,7 @@ def main(argv=None) -> int:
         compared, ref = harness.verdict(cell, seed, program, probed, loader.last_audit, packed)
         row = {"seed": seed, "program": _numbers(compared)}
         if seed in args.control_seeds:
+            reference = cell.module("references", cell.config["reference"])
             batches = [{k: a[k] for k in ("tokens", "segments", "loss_mask")} for a, _ in probed]
             for name, kw in (("control_bf16", {"dtype": jnp.bfloat16}), ("half_batch", {"drop_half": True})):
                 other = reference.train3(cell.config, seed, batches, **kw)

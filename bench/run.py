@@ -4,7 +4,8 @@
 
 Run from the root of a checkout on a machine that holds the cell's TPU
 chips.  The cells, metrics and bounds are in ``BENCHMARK.json``; a cell's
-configuration, traffic and limits are files under ``bench/`` found by name
+configuration, traffic and limits, its reference and operation counts, and
+the metrics' readers are files under ``bench/`` found by name
 (``bench/cell.py``).  ``--trace 0`` prints the cell's end-to-end metrics,
 ``--trace 1`` its per-layer metrics, read by ``bench/metrics/<name>.py``
 from the run's counters and a profiler trace of the window's last third.
@@ -55,14 +56,13 @@ def end_to_end(run: dict) -> dict:
 
 
 def result_line(cell, run: dict, trace: bool) -> dict:
-    import cell as cells
     import harness
 
     if trace:
         peaks = harness.peaks(run["device"]["kind"])
         metrics = {}
         for m in cell.per_layer:
-            value = cells.load_module("metrics", m["name"]).read(run, peaks)
+            value = cell.module("metrics", m["name"]).read(run, peaks)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     else:

@@ -19,7 +19,6 @@ import pytest
 import cell as cells
 import check
 import harness
-import reference
 
 REPO = pathlib.Path(__file__).resolve().parents[2]
 CELLS = [w["name"] for w in json.loads((REPO / "BENCHMARK.json").read_text())["workloads"]]
@@ -88,6 +87,7 @@ def test_control_is_not_correct(name):
     steps = [s for _, s in zip(range(3), loader.streaming_epoch(0))]
     batches = [{k: a[k] for k in ("tokens", "segments", "loss_mask")}
                for a in map(harness.global_arrays, steps)]
+    reference = c.module("references", c.config["reference"])
     ref = reference.train3(c.config, 5, batches)
     control = reference.train3(c.config, 5, batches, dtype=jnp.bfloat16)
     compared = check.compare(control, ref, 0, 0.0, c.limits)

@@ -28,6 +28,8 @@ def test_one_packed_row_by_hand():
     # the float32 log-sum-exp (1x2x8)
     assert nbytes == 2 * (2 * (4 * 64 + 4 * 32) + 4 * 16)
     assert calls == 2
+    assert dense_gqa.kernel_work(TOY, docs, (1, 8), elem_bytes=2) == {"flash": (ops, nbytes)}
+    assert dense_gqa.KERNELS == {"flash": "flash_fwd|flash_dq|flash_dkv"}
 
 
 @pytest.mark.parametrize("extra", [{"kv_lora_rank": 512}, {"num_experts": 8},

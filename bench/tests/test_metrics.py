@@ -7,7 +7,6 @@ import pytest
 from jax.profiler import ProfileData
 
 import cell as cells
-import named
 from test_named import TRACE
 
 NAMED = {  # reader -> hand count over the trace's 2 steps, ms per step
@@ -19,27 +18,27 @@ NAMED = {  # reader -> hand count over the trace's 2 steps, ms per step
 }
 
 
-def _write(root: pathlib.Path, text: str) -> None:
-    path = root / "cell" / "plugins" / "profile" / "1" / "host.xplane.pb"
+def write_trace(root: pathlib.Path, text: str) -> str:
+    """The trace ``text`` written where the harness writes a run's trace."""
+    path = root / ".bench_trace" / "cell" / "plugins" / "profile" / "1" / "host.xplane.pb"
     path.parent.mkdir(parents=True)
     path.write_bytes(ProfileData.text_proto_to_serialized_xspace(text))
+    return str(path)
 
 
 @pytest.mark.parametrize("metric", sorted(NAMED))
-def test_reader_counts_the_named_time_per_step(tmp_path, monkeypatch, metric):
-    _write(tmp_path, TRACE)
-    monkeypatch.setattr(named, "TRACE_ROOT", tmp_path)
-    value = cells.load_module("metrics", metric).read({"trace": {"steps": 2}}, {})
+def test_reader_counts_the_named_time_per_step(tmp_path, metric):
+    path = write_trace(tmp_path, TRACE)
+    value = cells.load_module("metrics", metric).read({"trace": {"steps": 2, "path": path}}, {})
     assert value == pytest.approx(NAMED[metric])
 
 
 @pytest.mark.parametrize("metric", sorted(NAMED))
-def test_reader_reads_none_without_its_names(tmp_path, monkeypatch, metric):
+def test_reader_reads_none_without_its_names(tmp_path, metric):
     text = TRACE
     for name in ("flash_fwd", "flash_dq", "flash_dkv", "adamw", "lm_loss", "train/realize"):
         text = text.replace(name, "plain")
-    _write(tmp_path, text)
-    monkeypatch.setattr(named, "TRACE_ROOT", tmp_path)
+    path = write_trace(tmp_path, text)
     reader = cells.load_module("metrics", metric)
-    assert reader.read({"trace": {"steps": 2}}, {}) is None
+    assert reader.read({"trace": {"steps": 2, "path": path}}, {}) is None
     assert reader.read({"window": {}}, {}) is None  # an untraced run

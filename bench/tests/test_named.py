@@ -79,6 +79,10 @@ planes { id: 2 name: "/host:CPU"
 """
 
 
+# the labels the benchmark's readers ask for
+READ = {"kernels": ("flash_fwd", "flash_dq", "flash_dkv"), "scopes": ("attention", "mlp", "lm_loss", "adamw")}
+
+
 def _reduced(text=TRACE):
     buf = ProfileData.text_proto_to_serialized_xspace(text)
     return named.reduce(
@@ -98,7 +102,7 @@ def test_op_names_come_from_the_event_metadata():
 def test_device_time_by_kernel_name_and_scope():
     red = _reduced()
     assert red["window_s"] == pytest.approx(200_000e-9)
-    assert red["named_s"] == pytest.approx({
+    assert named.labelled_s(red, **READ) == pytest.approx({
         "flash_fwd": 2_000e-9, "flash_dq": 4_000e-9, "flash_dkv": 3_000e-9,
         "adamw": 2_000e-9, "lm_loss": 1_000e-9, "mlp": 2_500e-9,
         "attention": 9_000e-9,  # the three kernels, not the loop that holds two
@@ -131,6 +135,36 @@ def test_a_trace_without_program_names_reads_none():
                  "train/realize", "train/log", "train/step"):
         text = text.replace(name, "plain")
     red = _reduced(text)
-    assert red["named_s"] == {}
+    assert named.labelled_s(red, **READ) == {}
     assert red["realize_idle_s"] is None
     assert dict(red["idle_by_span"])["bench/pull"] == pytest.approx(80_000e-9)
+
+
+def test_any_kernel_or_scope_label_gets_its_time(tmp_path):
+    # the dK/dV kernel renamed expert_gmm, the mlp fusion's scope renamed moe
+    text = TRACE.replace("flash_dkv", "expert_gmm").replace("/mlp/", "/moe/")
+    red = _reduced(text)
+    assert named.labelled_s(red, kernels=("expert_gmm",), scopes=("moe",)) == pytest.approx(
+        {"expert_gmm": 3_000e-9, "moe": 2_500e-9})
+    assert named.labelled_s(red, **READ)["flash_dq"] == pytest.approx(4_000e-9)  # the others keep theirs
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(text))
+    run = {"trace": {"steps": 2, "path": str(path)}}
+    assert named.kernel_ms(run, "expert_gmm") == pytest.approx(3_000e-6 / 2)
+    assert named.scope_ms(run, "moe") == pytest.approx(2_500e-6 / 2)
+    assert named.scope_ms(run, "moe", "adamw") == pytest.approx(4_500e-6 / 2)
+    assert named.kernel_ms(run, "moe") is None  # a scope is no kernel name
+    assert named.kernel_ms(run, "no_such_kernel") is None
+    assert named.kernel_ms(run, "flash_fwd") == pytest.approx(2_000e-6 / 2)
+    assert named.scope_ms(run, "attention") == pytest.approx(9_000e-6 / 2)
+
+
+def test_the_trace_is_walked_once_for_every_label(tmp_path):
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(TRACE))
+    run = {"trace": {"steps": 2, "path": str(path)}}
+    named._reduce_file.cache_clear()
+    readings = [named.kernel_ms(run, "flash_fwd"), named.kernel_ms(run, "flash_dq", "flash_dkv"),
+                named.scope_ms(run, "lm_loss"), named.scope_ms(run, "adamw"), named.for_run(run)]
+    assert all(r is not None for r in readings)
+    assert named._reduce_file.cache_info().misses == 1

@@ -64,3 +64,15 @@ def test_window_falls_back_to_the_pull_and_sync_spans():
     text = TRACE.replace('name: "bench/window"', 'name: "other"').replace('"bench/pull"', '"other2"')
     red = xplane.reduce(xplane.from_profile(ProfileData.from_text_proto(text)))
     assert red["window_s"] == pytest.approx(105_500e-9)  # first to last device op
+
+
+def test_a_kernel_family_counts_only_the_mosaic_calls_it_names():
+    import harness
+
+    text = TRACE.replace("%checkpoint.2 =", "%flash_fwd.2 =")
+    planes = xplane.from_profile(ProfileData.from_text_proto(text))
+    families = {"flash": "flash_fwd|flash_dq|flash_dkv", "gmm": "expert_gmm"}
+    red = xplane.reduce(planes, kernels=harness.kernel_patterns(families))
+    # the renamed kernel counts; %closed_call.7, a Mosaic call of no family, does not
+    assert red["kernel_s"] == pytest.approx({"flash": 1_000e-9, "gmm": 0.0})
+    assert xplane.reduce(planes, kernels=harness.kernel_patterns({}))["kernel_s"] == {}
